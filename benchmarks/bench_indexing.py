@@ -24,6 +24,7 @@ import time
 from repro.build import build_rlc_index_with_stats, get_backend
 from repro.build.parallel import ParallelBackend
 from repro.core.baselines import ETC
+from repro.device import on_cpu
 
 from .common import PAPER_GRAPH_STANDINS, Report, standin_graph
 
@@ -154,14 +155,6 @@ def _parallel_scaling(rep, summary, graphs, refs, numpy_s, k,
 # --------------------------------------------------------------------- #
 # Build-backend axis (staged pipeline: python vs numpy vs pallas)
 # --------------------------------------------------------------------- #
-def _pallas_on_device() -> bool:
-    try:
-        import jax
-        return jax.default_backend() != "cpu"
-    except Exception:
-        return False
-
-
 def run_backends(quick: bool = True, smoke: bool = False, k: int = 2,
                  scale: float = 1.0, repeats: int = 2) -> Report:
     """Per-backend build times on the stand-ins + equality check.
@@ -177,7 +170,7 @@ def run_backends(quick: bool = True, smoke: bool = False, k: int = 2,
         scale = min(scale, 0.3)
         repeats = 1
     backends = ["python", "numpy"]
-    if _pallas_on_device():
+    if not on_cpu():
         backends.append("pallas")
     totals = {b: 0.0 for b in backends}
     json_rows = []

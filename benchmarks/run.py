@@ -41,6 +41,8 @@ def main(argv=None):
     quick = not args.full
     smoke = args.smoke
     os.makedirs(ART, exist_ok=True)
+    from repro.device import enable_compile_cache
+    print(f"compile cache: {enable_compile_cache()}", flush=True)
 
     from . import (bench_delta, bench_device, bench_graph_chars,
                    bench_indexing, bench_k, bench_query, bench_scalability,

@@ -13,7 +13,8 @@ Backends (see ``README.md`` in this package for the design):
 ``python``    faithful sequential Algorithm 2 — the reference oracle
 ``numpy``     hybrid scalar / vectorized bitset waves on label CSR
 ``pallas``    hybrid with waves batched through the TPU ``frontier_step``
-              kernels (interpreted on CPU; request explicitly)
+              kernels (interpreted when JAX is on the CPU; request
+              explicitly)
 ``parallel``  hub-partitioned epoch/merge workers over a list-scheduled
               phase DAG (``workers=N``; each worker runs the numpy
               hybrid on a hub-sliced mirror)
@@ -34,10 +35,8 @@ from .base import (AUTO_ORDER, BuildBackend, BuildStats, PrunedInserter,
 from .reference import IndexBuilder, PythonBackend
 from .numpy_backend import NumpyBackend
 
-try:  # jax is optional at import time; the registry entry follows it
-    from .pallas_backend import PallasBackend  # noqa: F401
-except Exception:  # pragma: no cover - environments without jax
-    PallasBackend = None
+# imports jax only when a pallas build starts
+from .pallas_backend import PallasBackend
 
 # multi-worker epoch/merge construction over the phase DAG
 from .parallel import ParallelBackend

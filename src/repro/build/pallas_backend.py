@@ -12,11 +12,12 @@ than the f32 frontier it replaces).
 
 Hub batching deliberately stops at one hub: PR1 reads the entries every
 earlier hub completed, so cross-hub waves cannot stay bit-identical
-(see :mod:`repro.build.batched`). On a TPU the win is the per-hub wave
-batch; on CPU the kernels only *interpret*, so this backend defaults to
-hybrid dispatch (device waves for the widest hubs) and exists there for
-validation — request ``mode="vector"`` to force every hub through the
-kernel path, as the equivalence tests do.
+(see :mod:`repro.build.batched`). The backend defaults to hybrid
+dispatch (device waves for the widest hubs only); ``mode="vector"``
+sends every hub through the kernel path, as the equivalence tests do.
+When JAX runs on the CPU the kernels run in the Pallas interpreter —
+correct, and slow. Wave row counts are padded to a power of two so the
+device compiles a bounded set of shapes.
 """
 from __future__ import annotations
 
@@ -32,26 +33,21 @@ from .batched import BatchedBackend, FrontierEngine
 _EMPTY = np.empty(0, dtype=np.int64)
 
 
-def _on_cpu() -> bool:
-    try:
-        import jax
-        return jax.default_backend() == "cpu"
-    except Exception:
-        return True
-
-
 def _pad128(n: int) -> int:
     return max(128, -(-n // 128) * 128)
 
 
 class PallasEngine(FrontierEngine):
     def __init__(self, graph: LabeledGraph, interpret: Optional[bool] = None):
-        import jax.numpy as jnp  # deferred: backend is optional
+        import jax.numpy as jnp  # deferred: importing repro.build stays jax-free
+        from repro.device import on_cpu
 
         self.V = graph.num_vertices
         self.nl = graph.num_labels
         self.Vp = _pad128(self.V)
-        self.interpret = _on_cpu() if interpret is None else interpret
+        self.interpret = on_cpu() if interpret is None else interpret
+        self.waves = 0              # device waves run
+        self.shapes = set()         # distinct (rows, Vp) wave shapes
         A = np.zeros((self.nl, self.Vp, self.Vp), dtype=np.float32)
         e = graph.edges
         A[e[:, 1], e[:, 0], e[:, 2]] = 1
@@ -63,16 +59,20 @@ class PallasEngine(FrontierEngine):
               ) -> np.ndarray:
         """One device wave: returns the (R, V) boolean next frontier.
         The device result round-trips bit-packed (kernels/bitpack)."""
-        import jax.numpy as jnp
-        from repro.kernels.bitpack import pack_bits
-        from repro.kernels.label_frontier import frontier_step_many
+        from repro.kernels.ops import frontier_wave_packed
 
-        G = frontier_step_many(jnp.asarray(F), self._A[backward],
-                               jnp.asarray(labels.astype(np.int32)),
-                               interpret=self.interpret)
-        packed = np.asarray(pack_bits(G))               # (R, Vp/32) uint32
+        R = len(F)
+        Rp = 1 << (R - 1).bit_length()                # pow2 shape set
+        Fp = np.zeros((Rp, self.Vp), np.float32)
+        Fp[:R] = F
+        lab = np.zeros(Rp, np.int32)
+        lab[:R] = labels
+        self.waves += 1
+        self.shapes.add(Fp.shape)
+        packed = np.asarray(frontier_wave_packed(
+            Fp, self._A[backward], lab, interpret=self.interpret))[:R]
         bits = (packed[..., None] >> np.arange(32, dtype=np.uint32)) & 1
-        return bits.reshape(len(F), self.Vp)[:, :self.V].astype(bool)
+        return bits.reshape(R, self.Vp)[:, :self.V].astype(bool)
 
     def expand(self, rows: np.ndarray, ys: np.ndarray, rowlab: np.ndarray,
                dstrow: np.ndarray, backward: bool
@@ -113,9 +113,13 @@ class PallasBackend(BatchedBackend):
     def __init__(self, *args, interpret: Optional[bool] = None, **kw):
         super().__init__(*args, **kw)
         self.interpret = interpret
+        #: the last build's engine (its wave counters say how much of
+        #: the build ran on the device)
+        self.engine: Optional[PallasEngine] = None
 
     def _make_engine(self, graph: LabeledGraph) -> FrontierEngine:
-        return PallasEngine(graph, interpret=self.interpret)
+        self.engine = PallasEngine(graph, interpret=self.interpret)
+        return self.engine
 
 
 register_backend("pallas", PallasBackend)

@@ -159,7 +159,7 @@ class ParallelBackend(BuildBackend):
     ``workers``: engine count (default: the ``RLC_PARALLEL_WORKERS``
     env var, else 4 — the env knob is how CI exercises the protocol at
     a fixed width); ``executor``: ``"process"`` (one OS process per
-    worker, fork), ``"inline"`` (deterministic in-process —
+    worker; see :func:`~repro.build.parallel.worker._start_method`), ``"inline"`` (deterministic in-process —
     tests/1-core), or ``"auto"`` (process when ``workers > 1``).
     ``hot_prefix``/``locality`` shape the scheduling DAG (see
     :class:`~repro.build.parallel.dag.PhaseDAG`), and ``auto_thin``

@@ -276,6 +276,21 @@ def _worker_main(conn, graph, k, aid, engine_kw):  # pragma: no cover
             conn.send(("err", traceback.format_exc()))
 
 
+def _start_method() -> str:
+    """How build workers start: ``$RLC_PARALLEL_MP_CONTEXT`` when set,
+    else ``fork`` — unless this process has imported JAX. A JAX parent
+    may hold the accelerator runtime (on a TPU host, the chip itself) and
+    its threads, so it is never forked: workers then start from a clean
+    ``forkserver`` helper (callers' entry points must be import-guarded,
+    as any spawn-style start requires). The workers never import jax."""
+    import os
+    import sys
+    method = os.environ.get("RLC_PARALLEL_MP_CONTEXT")
+    if method:
+        return method
+    return "forkserver" if "jax" in sys.modules else "fork"
+
+
 class ProcessExecutor:
     """One OS process per worker, pipe-speaking the batch protocol.
     ``submit`` returns as soon as the job is on the pipe; ``recv_any``
@@ -288,21 +303,7 @@ class ProcessExecutor:
     def __init__(self, workers: int, graph: LabeledGraph, k: int,
                  aid: np.ndarray, **engine_kw):
         import multiprocessing as mp
-        import os
-        # fork is the only start method that works for arbitrary
-        # (un-import-guarded) caller scripts — spawn/forkserver re-import
-        # __main__ in the child. It does mean forking a parent whose jax
-        # runtime has live threads (the service path builds after jax is
-        # up), which CPython warns about; the workers themselves are
-        # jax-free and the pipes are the only shared state. Deployments
-        # that hit the fork-vs-threads hazard can set
-        # RLC_PARALLEL_MP_CONTEXT=forkserver (their entrypoints are
-        # import-guarded) — workers then fork from a clean helper.
-        method = os.environ.get("RLC_PARALLEL_MP_CONTEXT", "fork")
-        try:
-            ctx = mp.get_context(method)
-        except ValueError:  # pragma: no cover - non-fork platforms
-            ctx = mp.get_context()
+        ctx = mp.get_context(_start_method())
         self._conns = []
         self._procs = []
         self._inflight: set = set()

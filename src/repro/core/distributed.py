@@ -35,13 +35,6 @@ from .graph import LabeledGraph
 from .minimum_repeat import enumerate_mrs
 from .rlc_index import RLCIndex
 
-# jax promoted shard_map out of jax.experimental across versions.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def make_rlc_mesh(data: Optional[int] = None, pod: int = 1) -> Mesh:
     """1-pod mesh over available devices: axes ("pod", "data")."""
     nd = len(jax.devices())
@@ -57,7 +50,7 @@ def shmap_bool_matmul(mesh: Mesh, axis: str = "data"):
     """Returns an OR-AND matmul: left rows sharded over ``axis``; right
     operand all-gathered (tiled ring) inside the shard."""
 
-    @partial(_shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis, None), P(axis, None)),
              out_specs=P(axis, None))
     def matmul(a_blk, b_blk):

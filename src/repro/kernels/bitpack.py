@@ -18,11 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions; take
-# whichever this install provides.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def pack_bits(x: jax.Array) -> jax.Array:
     """(..., N) 0/1 -> (..., N//32) uint32 (bit j of word w = col 32w+j)."""
@@ -85,6 +80,6 @@ def bitpack_matmul(a: jax.Array, b_packed: jax.Array, *, bm: int = 128,
         out_shape=jax.ShapeDtypeStruct((m, w), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((bm, bw), jnp.uint32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a, b_packed)

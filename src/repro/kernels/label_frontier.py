@@ -34,7 +34,7 @@ def _frontier_kernel(lab_ref, f_ref, a_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(f_ref[...], a_ref[0],
+    acc_ref[...] += jnp.dot(f_ref[...], a_ref[...],
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
@@ -60,7 +60,8 @@ def frontier_step(frontier: jax.Array, A: jax.Array, label: jax.Array, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bb, bk), lambda i, j, kk, lab: (i, kk)),
-            pl.BlockSpec((1, bk, bn), lambda i, j, kk, lab: (lab[0], kk, j)),
+            pl.BlockSpec((None, bk, bn),
+                         lambda i, j, kk, lab: (lab[0], kk, j)),
         ],
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, kk, lab: (i, j)),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
@@ -70,6 +71,7 @@ def frontier_step(frontier: jax.Array, A: jax.Array, label: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, V), frontier.dtype),
         interpret=interpret,
+        name="rlc_frontier_step",
     )(label.reshape(1).astype(jnp.int32), frontier, A)
 
 
@@ -91,23 +93,29 @@ def frontier_step_many(frontier: jax.Array, A: jax.Array,
     bk, bn = min(bk, V), min(bn, V)
     assert V % bk == 0 and V % bn == 0
     grid = (R, V // bn, V // bk)
+    # one frontier row per grid step: rows ride as (R, 1, V) so each
+    # block's last two dims are (1, 128·m), which the TPU tiling rule
+    # accepts (a (1, bk) block of an (R, V) array it refuses)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bk), lambda i, j, kk, lab: (i, kk)),
-            pl.BlockSpec((1, bk, bn),
+            pl.BlockSpec((None, 1, bk), lambda i, j, kk, lab: (i, 0, kk)),
+            pl.BlockSpec((None, bk, bn),
                          lambda i, j, kk, lab: (lab[i], kk, j)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j, kk, lab: (i, j)),
+        out_specs=pl.BlockSpec((None, 1, bn),
+                               lambda i, j, kk, lab: (i, 0, j)),
         scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_frontier_kernel, k_steps=grid[2]),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, V), frontier.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, 1, V), frontier.dtype),
         interpret=interpret,
-    )(labels.astype(jnp.int32), frontier, A)
+        name="rlc_frontier_step_many",
+    )(labels.astype(jnp.int32), frontier.reshape(R, 1, V), A)
+    return out.reshape(R, V)
 
 
 def frontier_steps(frontier: jax.Array, A: jax.Array, labels: jax.Array,

@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels: padding to block multiples,
-interpret-mode dispatch on CPU (the container has no TPU — kernels are
-authored for TPU and validated via the interpreter), and a uniform
-``matmul``-shaped interface the dense engine can plug in.
+interpret-mode dispatch when JAX runs on the CPU (kernels compile for the
+TPU; on the host they run in the Pallas interpreter, which the CPU tests
+use), and a uniform ``matmul``-shaped interface the dense engine can plug
+in. ``interpret=None`` asks :func:`repro.device.on_cpu` at trace time.
 """
 from __future__ import annotations
 
@@ -11,12 +12,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.device import on_cpu
+
 from . import bitpack as _bitpack
 from . import bool_semiring as _bs
 from . import label_frontier as _lf
 from . import mergejoin as _mj
 
-_ON_CPU = jax.default_backend() == "cpu"
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    return on_cpu() if interpret is None else interpret
 
 
 def _pad_to(x: jax.Array, mults):
@@ -34,7 +39,7 @@ def bool_matmul(a: jax.Array, b: jax.Array, bm: int = 128, bk: int = 128,
                 bn: int = 128, interpret: Optional[bool] = None
                 ) -> jax.Array:
     """Padded OR-AND semiring matmul via the Pallas kernel."""
-    interpret = _ON_CPU if interpret is None else interpret
+    interpret = _interpret(interpret)
     m, k = a.shape
     _, n = b.shape
     bm_, bk_, bn_ = min(bm, m), min(bk, k), min(bn, n)
@@ -48,7 +53,7 @@ def bool_matmul(a: jax.Array, b: jax.Array, bm: int = 128, bk: int = 128,
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
 def closure_step(r: jax.Array, bm: int = 128, bk: int = 128, bn: int = 128,
                  interpret: Optional[bool] = None) -> jax.Array:
-    interpret = _ON_CPU if interpret is None else interpret
+    interpret = _interpret(interpret)
     n = r.shape[0]
     b = min(bm, n)
     rp = _pad_to(r, (b, b))
@@ -64,7 +69,7 @@ def mergejoin_query(out_hub, out_mr, in_hub, in_mr, s, t, mr,
                     interpret: Optional[bool] = None,
                     row_base_out: int = 0,
                     row_base_in: int = 0) -> jax.Array:
-    interpret = _ON_CPU if interpret is None else interpret
+    interpret = _interpret(interpret)
     return _mj.query_batch(out_hub, out_mr, in_hub, in_mr, s, t, mr,
                            interpret=interpret, row_base_out=row_base_out,
                            row_base_in=row_base_in)
@@ -72,7 +77,7 @@ def mergejoin_query(out_hub, out_mr, in_hub, in_mr, s, t, mr,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bitpack_matmul(a, b_packed, interpret: Optional[bool] = None):
-    interpret = _ON_CPU if interpret is None else interpret
+    interpret = _interpret(interpret)
     m, k = a.shape
     _, w = b_packed.shape
     bm, bk, bw = min(128, m), min(128, k), min(128, w)
@@ -85,7 +90,7 @@ def bitpack_matmul(a, b_packed, interpret: Optional[bool] = None):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def frontier_step(frontier, A, label, interpret: Optional[bool] = None):
-    interpret = _ON_CPU if interpret is None else interpret
+    interpret = _interpret(interpret)
     B, V = frontier.shape
     bb, bk = min(128, B), min(128, V)
     fp = _pad_to(frontier, (bb, bk))
@@ -94,6 +99,15 @@ def frontier_step(frontier, A, label, interpret: Optional[bool] = None):
                             bk=min(128, Ap.shape[1]),
                             bn=min(128, Ap.shape[2]), interpret=interpret)
     return out[:B, :V]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def frontier_wave_packed(frontier, A, labels,
+                         interpret: Optional[bool] = None):
+    """One per-row-label frontier wave, returned bit-packed
+    (``(R, V // 32)`` uint32): the device build's round trip."""
+    return _bitpack.pack_bits(_lf.frontier_step_many(
+        frontier, A, labels, interpret=_interpret(interpret)))
 
 
 pack_bits = _bitpack.pack_bits

@@ -11,13 +11,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist in
-    # newer jax; older installs default every axis to Auto anyway.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -37,10 +32,8 @@ def make_elastic_mesh(data: int, model: int = 16, pod: int = 1):
 
 
 def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` on new jax; the classic ``with mesh:``
-    physical-mesh context on jax 0.4.x (where set_mesh doesn't exist)."""
-    setter = getattr(jax, "set_mesh", None)
-    return setter(mesh) if setter is not None else mesh
+    """Context manager that makes ``mesh`` the current mesh."""
+    return jax.set_mesh(mesh)
 
 
 def make_host_mesh(model: int = 1):

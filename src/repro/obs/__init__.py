@@ -11,7 +11,7 @@ ad-hoc ``stats()`` dicts:
   memory everywhere.
 * :mod:`repro.obs.tracing` — sampling-controlled per-query span tracing
   (parse -> cache probe -> queue wait -> shard route -> digest hand-off
-  -> executor backend -> fallback chain) with a Chrome ``trace_event``
+  -> executor backend) with a Chrome ``trace_event``
   exporter.
 * :mod:`repro.obs.export` — a versioned JSON snapshot schema (asserted
   by ``tests/test_obs.py`` and validated by the benchmark smoke run)

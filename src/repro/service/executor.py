@@ -7,15 +7,18 @@ One interface over the four existing engines:
 * ``numpy``  — frozen CSR merge-join (:meth:`FrozenRLCIndex.query_batch`);
 * ``sorted`` — XLA sorted-key intersection on the padded device layout
   (:meth:`DeviceIndex.query_batch` with ``method="sorted"``);
-* ``pallas`` — the Pallas dense merge-join kernel (interpreted on CPU).
+* ``pallas`` — the Pallas merge-join kernel (compiled for the TPU; run by
+  the Pallas interpreter when JAX is on the CPU).
 
-Backends that need a :class:`DeviceIndex` degrade gracefully: when the
-device layout is absent or a device dispatch raises, the executor walks a
-fallback chain toward ``python`` and records which backend actually
-answered. Per-backend latency/throughput lands in
+A backend that is *unavailable* — the device backends without a
+:class:`DeviceIndex`, ``numpy`` without the frozen CSR — is skipped for
+the first available one in :data:`BACKENDS` order, and the skip is counted
+as a fallback. A backend that *fails* is a fault: the executor raises
+:class:`ExecutorError` chained to the cause and never hides it behind a
+host backend. Per-backend latency/throughput lands in
 :class:`repro.service.metrics.LatencyRecorder` and — when an
 :class:`repro.obs.Observability` is attached — in the shared metrics
-registry (labeled by backend and shard), with per-attempt spans when the
+registry (labeled by backend and shard), with a span per batch when the
 batch rides a sampled trace.
 """
 from __future__ import annotations
@@ -27,6 +30,7 @@ import numpy as np
 
 from repro.core.minimum_repeat import LabelSeq
 from repro.core.rlc_index import FrozenRLCIndex, RLCIndex
+from repro.device import on_cpu
 from repro.obs import NULL_OBS
 
 from .metrics import LatencyRecorder
@@ -35,16 +39,9 @@ from .metrics import LatencyRecorder
 BACKENDS = ("pallas", "sorted", "numpy", "python")
 
 
-def _on_cpu() -> bool:
-    try:
-        import jax
-        return jax.default_backend() == "cpu"
-    except Exception:
-        return True
-
-
 class ExecutorError(RuntimeError):
-    """Raised when no backend (including the fallbacks) can run a batch."""
+    """Raised when no backend is available for a batch, or when the
+    backend that ran it failed (chained to the cause)."""
 
 
 class BatchExecutor:
@@ -107,7 +104,7 @@ class BatchExecutor:
         b = backend or self.backend
         if b == "auto":
             order = BACKENDS
-            if _on_cpu():
+            if on_cpu():
                 # the Pallas kernel only *interprets* on CPU — the XLA
                 # sorted-key path is the fast lowering there.
                 order = ("sorted", "numpy", "pallas", "python")
@@ -124,45 +121,42 @@ class BatchExecutor:
                 trace=None) -> Tuple[np.ndarray, str]:
         """Answer a padded batch; returns ``(answers[:n_real], backend)``.
 
-        Tries the requested backend, then every remaining backend in
-        ``BACKENDS`` order (a device failure must never fail the query —
-        the python reference can always answer). ``trace``: optional
-        :class:`repro.obs.Trace`; each attempt gets an ``exec:<backend>``
-        span, so a fallback chain is visible as consecutive spans.
+        Runs the requested backend, or the first available one in
+        ``BACKENDS`` order when it is unavailable (counted as a
+        fallback). A backend that raises is not retried elsewhere: the
+        error propagates as :class:`ExecutorError`. ``trace``: optional
+        :class:`repro.obs.Trace`; the batch gets an ``exec:<backend>``
+        span.
         """
         first = self.resolve(backend)
-        chain = [first] + [b for b in BACKENDS
-                           if b != first and self.available(b)]
+        b = next((c for c in (first,) + BACKENDS if self.available(c)),
+                 None)
+        if b is None:
+            raise ExecutorError(f"no backend available for {first!r}")
         n = len(s) if n_real is None else int(n_real)
-        last_err: Optional[Exception] = None
-        for i, b in enumerate(chain):
-            if not self.available(b):
-                continue
-            try:
-                t0 = time.perf_counter()
-                ans = self._run(b, s, t, mr_id, n)
+        t0 = time.perf_counter()
+        try:
+            ans = self._run(b, s, t, mr_id, n)
+        except Exception as e:
+            if trace is not None:
                 dt = time.perf_counter() - t0
-                self.recorders[b].record(dt, n)
-                self._m_lat[b].observe(dt)
-                self._m_bat[b].inc()
-                self._m_qry[b].inc(n)
-                if trace is not None:
-                    trace.add(f"exec:{b}", trace.tracer._now() - dt, dt,
-                              cat="executor", n=n, fallback=i > 0)
-                if i > 0:
-                    self.fallbacks += 1
-                    self._m_fallback.labels(
-                        **{"from": first, "to": b,
-                           "shard": self._shard}).inc()
-                return np.asarray(ans[:n], dtype=bool), b
-            except Exception as e:  # noqa: BLE001 — fall through the chain
-                last_err = e
-                if trace is not None:
-                    dt = time.perf_counter() - t0
-                    trace.add(f"exec:{b}", trace.tracer._now() - dt, dt,
-                              cat="executor", error=type(e).__name__)
-        raise ExecutorError(
-            f"all backends failed for batch of {n} queries") from last_err
+                trace.add(f"exec:{b}", trace.tracer._now() - dt, dt,
+                          cat="executor", error=type(e).__name__)
+            raise ExecutorError(
+                f"backend {b!r} failed on a batch of {n} queries") from e
+        dt = time.perf_counter() - t0
+        self.recorders[b].record(dt, n)
+        self._m_lat[b].observe(dt)
+        self._m_bat[b].inc()
+        self._m_qry[b].inc(n)
+        if trace is not None:
+            trace.add(f"exec:{b}", trace.tracer._now() - dt, dt,
+                      cat="executor", n=n, fallback=b != first)
+        if b != first:
+            self.fallbacks += 1
+            self._m_fallback.labels(
+                **{"from": first, "to": b, "shard": self._shard}).inc()
+        return np.asarray(ans[:n], dtype=bool), b
 
     def explain_batch(self, s: np.ndarray, t: np.ndarray,
                       mr_id: np.ndarray, n_real: Optional[int] = None,
@@ -175,18 +169,13 @@ class BatchExecutor:
         witness reflects the layout the serving path would actually join
         — device backends explain over the padded/truncated device rows,
         ``numpy`` over the frozen CSR, ``python`` over the dict layout.
-        Device failures degrade the same way the serving path does.
         """
         first = self.resolve(backend)
         n = len(s) if n_real is None else int(n_real)
         if first in ("pallas", "sorted") and self.device_index is not None:
-            try:
-                ws = self.device_index.explain_batch(s[:n], t[:n],
-                                                     mr_id[:n],
-                                                     max_hubs=max_hubs)
-                return ws, first
-            except Exception:  # noqa: BLE001 — degrade like execute()
-                pass
+            ws = self.device_index.explain_batch(s[:n], t[:n], mr_id[:n],
+                                                 max_hubs=max_hubs)
+            return ws, first
         if self.frozen is not None:
             ws = [self.frozen.explain(int(s[q]), int(t[q]),
                                       int(mr_id[q]), max_hubs=max_hubs)

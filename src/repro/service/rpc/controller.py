@@ -131,11 +131,6 @@ class RpcShardCluster:
         self.leaves = 0
         self.rejoins = 0
         self.retries = 0
-        try:        # per-worker round-trip watch (repro.ft.elastic)
-            from repro.ft.elastic import StragglerMonitor
-            self._straggler_cls = StragglerMonitor
-        except Exception:                     # pragma: no cover - no jax
-            self._straggler_cls = None
         self.obs = obs or NULL_OBS
         reg = self.obs.registry
         self._m_bytes = reg.counter(
@@ -206,8 +201,9 @@ class RpcShardCluster:
         self._call(h, "init", shard_id=h.shard_id,
                    replica_id=h.replica_id, **payload)
         h.generation = int(payload["generation"])
-        if self._straggler_cls is not None:
-            h.straggler = self._straggler_cls(window=32, factor=4.0)
+        # per-worker round-trip watch
+        from repro.ft.elastic import StragglerMonitor
+        h.straggler = StragglerMonitor(window=32, factor=4.0)
 
     def _on_join(self, event: str) -> None:
         self.membership_epoch += 1

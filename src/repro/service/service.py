@@ -113,15 +113,14 @@ class RLCService:
         self.mr_ids = mr_id_space(graph.num_labels, config.k)
         self._id_to_mr: List[LabelSeq] = [
             mr for mr, _ in sorted(self.mr_ids.items(), key=lambda kv: kv[1])]
+        t0 = time.perf_counter()
         self.frozen = index.freeze(self.mr_ids)
-        self.device_index = None
-        if config.use_device:
-            try:
-                from repro.core.device_index import DeviceIndex
-                self.device_index = DeviceIndex.from_frozen(
-                    self.frozen, self.mr_ids)
-            except Exception:   # no jax / no device: CPU-only degraded mode
-                self.device_index = None
+        t1 = time.perf_counter()
+        self.device_index = self._make_device_index()
+        #: host seconds of the serving setup: the CSR freeze, and the
+        #: padded device layout (row packing, key sort, transfer enqueue)
+        self.setup_seconds = dict(freeze=t1 - t0,
+                                  device=time.perf_counter() - t1)
         self.executor = BatchExecutor(
             index, self.frozen, self.device_index, self._id_to_mr,
             backend=config.backend, obs=self.obs)
@@ -402,13 +401,12 @@ class RLCService:
         return b if b not in ("auto", "python", "parallel") else "numpy"
 
     def _make_device_index(self):
+        """The padded device layout of ``self.frozen``; None only when
+        ``use_device`` is off. A layout that cannot be built raises."""
         if not self.config.use_device:
             return None
-        try:
-            from repro.core.device_index import DeviceIndex
-            return DeviceIndex.from_frozen(self.frozen, self.mr_ids)
-        except Exception:   # no jax / no device: CPU-only degraded mode
-            return None
+        from repro.core.device_index import DeviceIndex
+        return DeviceIndex.from_frozen(self.frozen, self.mr_ids)
 
     def _ensure_delta_builder(self):
         """Bootstrap the incremental builder on first use: one traced
@@ -440,8 +438,7 @@ class RLCService:
         self.index = db.index
         self.build_stats = db.stats
         self.frozen = self.index.freeze(self.mr_ids)
-        if self.device_index is not None:
-            self.device_index = self._make_device_index()
+        self.device_index = self._make_device_index()
         self.executor.index = self.index
         self.executor.frozen = self.frozen
         self.executor.device_index = self.device_index
@@ -471,8 +468,7 @@ class RLCService:
                 self.index, self.mr_ids,
                 set(res.dirty_out.tolist()) | set(res.resort_out.tolist()),
                 set(res.dirty_in.tolist()) | set(res.resort_in.tolist()))
-        if self.device_index is not None:
-            self.device_index = self._make_device_index()
+        self.device_index = self._make_device_index()
         # the executor keeps its latency recorders; only the index
         # references move. Repoint BEFORE invalidating the cache: a
         # concurrent ticker flush that executed on the old index must not

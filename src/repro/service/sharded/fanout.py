@@ -14,8 +14,9 @@ back into admission order:
   ships them to shard *j*'s device (simulated one-hop transfer;
   ``jax.device_put`` when the shards are pinned to different devices),
   where :func:`repro.core.device_index.join_rows` merge-joins digests
-  against *j*'s local in-rows. Without device layouts the same join runs
-  row-by-row through :func:`repro.core.rlc_index.merge_join_rows`.
+  against *j*'s local in-rows. Without device layouts (``use_device``
+  off) the same join runs row-by-row through
+  :func:`repro.core.rlc_index.merge_join_rows`.
 
 Sub-batches are padded to the next power of two (capped at the admission
 batch size) by repeating their first request, so each shard pair sees a
@@ -40,6 +41,7 @@ from repro.core.rlc_index import merge_join_rows
 from repro.obs import NULL_OBS
 
 from ..metrics import LatencyRecorder
+from ..rpc.controller import WorkerLost
 from ..scheduler import Batch
 from .replica import ShardReplica, ShardReplicaSet
 from .router import TwoSidedRouter
@@ -58,6 +60,11 @@ def _pad_pow2(vals: List[int], cap: int) -> np.ndarray:
 
 
 class ScatterGatherExecutor:
+    #: sub-batch failures the BiBFS degrade path answers: a lost worker
+    #: process under the RPC transport. In-process shards have no
+    #: transport to lose, so any failure there is a fault and raises.
+    transport_errors: Tuple[type, ...] = ()
+
     def __init__(self, shards: List[ShardReplicaSet],
                  router: TwoSidedRouter, batch_size: int, obs=None,
                  graph=None, id_to_mr=None):
@@ -173,9 +180,9 @@ class ScatterGatherExecutor:
             try:
                 ans, backend = self._run_sub(ss, st, s, t, mr, len(idxs),
                                              trace=trace)
-            except Exception:
-                # transport failure (e.g. every worker of a shard died
-                # mid-call): the degrade path still answers exactly
+            except self.transport_errors:
+                # every worker of a shard died mid-call: the degrade path
+                # still answers exactly
                 if not can_degrade:
                     raise
                 ans, backend = self._degrade_bibfs(reqs, idxs), "bibfs"
@@ -206,13 +213,10 @@ class ScatterGatherExecutor:
         src = self.shards[ss].acquire()
         dst = self.shards[st].acquire()
         if src.device_index is not None and dst.device_index is not None:
-            try:
-                ans = self._join_device(src, dst, s, t, mr, n_real)
-                self.remote_joins_device += 1
-                self._m_join["device"].inc()
-                return ans[:n_real]
-            except Exception:
-                pass    # device trouble: the numpy join always works
+            ans = self._join_device(src, dst, s, t, mr, n_real)
+            self.remote_joins_device += 1
+            self._m_join["device"].inc()
+            return ans[:n_real]
         self.remote_joins_numpy += 1
         self._m_join["numpy"].inc()
         return self._join_numpy(src, dst, s[:n_real], t[:n_real],
@@ -233,9 +237,8 @@ class ScatterGatherExecutor:
                                    jnp.asarray(s, jnp.int32),
                                    jnp.asarray(t, jnp.int32),
                                    jnp.asarray(mr, jnp.int32)))
-        # traffic accounting only after the join succeeded (a failure falls
-        # back to the numpy join, which does its own counting) — real rows
-        # only, padding ships just for the jit shape
+        # traffic accounting counts real rows only; padding ships just
+        # for the jit shape
         nbytes = 2 * n_real * int(oh.shape[1]) * 4
         self.digest_bytes += nbytes
         self._m_digest.inc(nbytes)
@@ -284,6 +287,8 @@ class RpcScatterGatherExecutor(ScatterGatherExecutor):
     escaping a sub-batch is caught by the base class and answered by
     BiBFS — exact answers survive total shard loss.
     """
+
+    transport_errors = (WorkerLost,)
 
     def __init__(self, cluster, router: TwoSidedRouter, batch_size: int,
                  obs=None, graph=None, id_to_mr=None):

@@ -12,8 +12,8 @@ there is never a moment when a replica is half-swapped.
 
 When the host exposes multiple JAX devices, each shard's device arrays are
 placed round-robin across them (`shard_id % len(devices)`) — in-process
-workers standing in for real multi-host placement; a failed placement
-degrades to the default device rather than to no device layout.
+workers standing in for real multi-host placement. A layout that cannot be
+built or placed raises; nothing serves in its place.
 """
 from __future__ import annotations
 
@@ -70,43 +70,37 @@ class ShardReplica:
     replica_id: int
     generation: int
     frozen: FrozenRLCIndex          # slice view: rows [lo, hi) populated
-    device_index: Optional[object]  # DeviceIndex or None (degraded mode)
+    device_index: Optional[object]  # DeviceIndex, or None (use_device off)
     executor: BatchExecutor
     device: Optional[object] = None  # jax.Device this replica is pinned to
 
 
 def _pin(device_index, device):
-    """Move a DeviceIndex's arrays onto ``device`` (best-effort)."""
-    if device_index is None or device is None:
+    """Move a DeviceIndex's arrays onto ``device`` (None: leave them on
+    the default device)."""
+    if device is None:
         return device_index
-    try:
-        import jax
-        put = lambda a: (jax.device_put(a, device)  # noqa: E731
-                         if isinstance(a, jax.Array) else a)
-        return dataclasses.replace(
-            device_index,
-            out_hub=put(device_index.out_hub),
-            out_mr=put(device_index.out_mr),
-            in_hub=put(device_index.in_hub),
-            in_mr=put(device_index.in_mr),
-            out_key=put(device_index.out_key),
-            in_key=put(device_index.in_key))
-    except Exception:
-        return device_index
+    import jax
+    put = lambda a: jax.device_put(a, device)  # noqa: E731
+    return dataclasses.replace(
+        device_index,
+        out_hub=put(device_index.out_hub),
+        out_mr=put(device_index.out_mr),
+        in_hub=put(device_index.in_hub),
+        in_mr=put(device_index.in_mr),
+        out_key=put(device_index.out_key),
+        in_key=put(device_index.in_key))
 
 
 def build_device_layout(frozen_slice: FrozenRLCIndex, mr_ids,
                         rows: Optional[Tuple[int, int]] = None,
                         device=None):
-    """Row-windowed device layout for one shard slice, or None (degraded
-    CPU-only mode). Built once per (shard, generation) and shared by every
-    replica pinned to the same device — the arrays are immutable."""
-    try:
-        from repro.core.device_index import DeviceIndex
-        return _pin(DeviceIndex.from_frozen(frozen_slice, mr_ids,
-                                            rows=rows), device)
-    except Exception:   # no jax / no device
-        return None
+    """Row-windowed device layout for one shard slice. Built once per
+    (shard, generation) and shared by every replica pinned to the same
+    device — the arrays are immutable."""
+    from repro.core.device_index import DeviceIndex
+    return _pin(DeviceIndex.from_frozen(frozen_slice, mr_ids, rows=rows),
+                device)
 
 
 def build_replica(shard_id: int, replica_id: int, generation: int,

@@ -57,14 +57,11 @@ class ShardedServiceConfig(ServiceConfig):
 def _shard_devices(num_shards: int) -> List[Optional[object]]:
     """Round-robin shard -> device placement when >1 device is visible
     (in-process stand-in for multi-host; None pins nothing)."""
-    try:
-        import jax
-        devs = jax.devices()
-        if len(devs) > 1:
-            return [devs[i % len(devs)] for i in range(num_shards)]
-    except Exception:
-        pass
-    return [None] * num_shards
+    import jax
+    devs = jax.devices()
+    if len(devs) == 1:
+        return [None] * num_shards
+    return [devs[i % len(devs)] for i in range(num_shards)]
 
 
 class ShardedRLCService:

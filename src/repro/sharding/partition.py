@@ -96,14 +96,8 @@ def constrain(x: jax.Array, axes: Axes, rules: Optional[Dict] = None
               ) -> jax.Array:
     """with_sharding_constraint under the ambient mesh (no-op when no mesh
     is set — smoke tests and benches run unconstrained on 1 device)."""
-    mesh = None
-    try:
-        env = jax.sharding.get_abstract_mesh()
-        if env is not None and env.axis_names:
-            mesh = env
-    except Exception:
-        mesh = None
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     spec = logical_to_spec(x.shape, axes, mesh, rules or ACT_RULES)
     return jax.lax.with_sharding_constraint(x, spec)

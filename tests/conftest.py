@@ -10,15 +10,3 @@ if _SRC not in sys.path:
 # Smoke tests and benches must see exactly ONE device; only launch/dryrun.py
 # sets the 512-device flag (in its own process, before importing jax).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-# The container has no `hypothesis` and nothing may be pip-installed; fall
-# back to the deterministic stub so the property-test modules still run.
-_TESTS = os.path.dirname(os.path.abspath(__file__))
-if _TESTS not in sys.path:
-    sys.path.insert(0, _TESTS)
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    import _hypothesis_stub
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies

@@ -73,6 +73,34 @@ def test_mergejoin_matches_ref(n, E, Q):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("n,base", [(13, 0), (13, 100), (21, 5), (3, 7)])
+def test_mergejoin_row_window_matches_ref(n, base):
+    """A shard's window: rows [base, base + n) with global ids, n not a
+    multiple of the kernel's 8-row group (a partial last group)."""
+    E, Q, V = 16, 24, base + n
+    rng = np.random.default_rng(n * 31 + base)
+
+    def rows():
+        hub = np.full((V, E), -1, np.int32)
+        mr = np.full((V, E), -1, np.int32)
+        hub[base:] = rng.integers(-1, V, size=(n, E))
+        mr[base:] = rng.integers(0, 4, size=(n, E))
+        mr[hub == -1] = -1
+        return hub, mr
+    oh, om = rows()
+    ih, im = rows()
+    s = rng.integers(base, V, Q).astype(np.int32)
+    t = rng.integers(base, V, Q).astype(np.int32)
+    mr = rng.integers(0, 4, Q).astype(np.int32)
+    got = ops.mergejoin_query(*(jnp.asarray(a[base:]) for a in (oh, om, ih,
+                                                                im)),
+                              s, t, mr, interpret=True, row_base_out=base,
+                              row_base_in=base)
+    want = ref.mergejoin_ref(*(jnp.asarray(a) for a in (oh, om, ih, im, s, t,
+                                                        mr)))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_mergejoin_on_real_index():
     from repro.core.device_index import DeviceIndex
     from repro.core.index_builder import build_rlc_index

@@ -240,3 +240,37 @@ def test_forced_conflict_sweep_slow(seed):
     g = erdos_renyi(50, 3.0, 3, seed=seed)
     assert_bit_identical(g, 2, workers=4, hot_prefix=0, locality=0,
                          auto_thin=False)
+
+
+@pytest.mark.parametrize("module", ["repro.build.parallel.worker",
+                                    "repro.service.rpc.worker",
+                                    "repro.service.executor",
+                                    "repro.service.sharded.replica"])
+def test_worker_modules_do_not_import_jax(module):
+    """Worker children import their module (an RPC shard worker also the
+    executor and the replica slice helpers): none may load jax, or a
+    child would contend with its parent for the accelerator."""
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    code = (f"import sys; import {module}; "
+            "assert 'jax' not in sys.modules, sorted("
+            "m for m in sys.modules if m.startswith('jax'))")
+    r = subprocess.run([sys.executable, "-c", code], env=dict(
+        os.environ, PYTHONPATH=src), capture_output=True, text=True,
+        timeout=120)
+    assert r.returncode == 0, r.stderr
+
+
+def test_process_workers_never_fork_a_jax_parent(monkeypatch):
+    import sys
+
+    import jax  # noqa: F401 — this process is now a JAX parent
+    from repro.build.parallel.worker import _start_method
+    monkeypatch.delenv("RLC_PARALLEL_MP_CONTEXT", raising=False)
+    assert _start_method() == "forkserver"
+    monkeypatch.delitem(sys.modules, "jax")
+    assert _start_method() == "fork"
+    monkeypatch.setenv("RLC_PARALLEL_MP_CONTEXT", "spawn")
+    assert _start_method() == "spawn"

@@ -9,9 +9,9 @@ from repro.core.index_builder import build_rlc_index
 from repro.core.minimum_repeat import enumerate_mrs
 from repro.core.queries import biased_true_queries
 from repro.graphgen import erdos_renyi, fig1_graph
-from repro.service import (BatchExecutor, ExpressionError, MicroBatcher,
-                           RLCService, ResultCache, ServiceConfig,
-                           parse_expression)
+from repro.service import (BatchExecutor, ExecutorError, ExpressionError,
+                           MicroBatcher, RLCService, ResultCache,
+                           ServiceConfig, parse_expression)
 
 
 # ------------------------------------------------------------------ #
@@ -315,6 +315,8 @@ def test_executor_fallback_when_device_missing(small_setup):
 
 
 def test_executor_fallback_on_backend_failure(small_setup):
+    """A device backend that fails raises; it never falls through to a
+    host backend that would hide the fault."""
     g, svc, queries = small_setup
 
     class Boom:
@@ -328,11 +330,33 @@ def test_executor_fallback_on_backend_failure(small_setup):
     s = np.array([q[0] for q in queries[:4]], np.int32)
     t = np.array([q[1] for q in queries[:4]], np.int32)
     mr = np.array([svc.mr_ids[q[2]] for q in queries[:4]], np.int32)
-    got, backend = ex.execute(s, t, mr)
-    assert backend in ("numpy", "python")   # fell through the chain
-    assert ex.fallbacks == 1
+    with pytest.raises(ExecutorError, match="'sorted' failed") as err:
+        ex.execute(s, t, mr)
+    assert isinstance(err.value.__cause__, RuntimeError)
+    assert ex.fallbacks == 0
+    assert "sorted" not in ex.stats() and "numpy" not in ex.stats()
+    # the host backends still answer when asked for explicitly
+    got, backend = ex.execute(s, t, mr, backend="numpy")
     ref, _ = ex.execute(s, t, mr, backend="python")
+    assert backend == "numpy"
     np.testing.assert_array_equal(got, ref)
+
+
+def test_device_layout_failure_raises_at_build(monkeypatch):
+    """use_device=True with a device layout that cannot be built raises;
+    the service never comes up serving from the host instead."""
+    from repro.core.device_index import DeviceIndex
+
+    def boom(*a, **kw):
+        raise RuntimeError("no device memory")
+
+    g = erdos_renyi(30, 3.0, 3, seed=2)
+    monkeypatch.setattr(DeviceIndex, "from_frozen", boom)
+    with pytest.raises(RuntimeError, match="no device memory"):
+        RLCService.build(g, ServiceConfig(k=2))
+    svc = RLCService.build(g, ServiceConfig(k=2, use_device=False))
+    assert svc.device_index is None and svc.query(0, 1, "(0)+") in (
+        True, False)
 
 
 def test_executor_records_per_backend_metrics(small_setup):

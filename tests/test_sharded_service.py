@@ -135,12 +135,12 @@ def test_router_invariant_home_is_shard_t():
 
 
 # ------------------------------------------------------------------ #
-# Sharded vs single-host agreement (property, hypothesis stub)
+# Sharded vs single-host agreement (property)
 # ------------------------------------------------------------------ #
-@settings(max_examples=4)
+@settings(max_examples=4, deadline=None)
 @given(st.integers(0, 10_000), st.integers(40, 70))
 def test_sharded_matches_single_host_and_oracle(seed, n):
-    """>= 3 random graphs (4 stub examples) x shards {1,2,4} x replicas
+    """>= 3 random graphs (4 examples) x shards {1,2,4} x replicas
     {1,2}: bit-identical to RLCService and the BiBFS oracle."""
     g = erdos_renyi(n, 3.5, 3, seed=seed)
     base = RLCService.build(
@@ -257,8 +257,6 @@ def test_replicas_share_windowed_device_layout():
         g, ShardedServiceConfig(k=2, num_shards=4, num_replicas=2))
     for rs in svc.shards:
         r0, r1 = rs.replicas
-        if r0.device_index is None:
-            continue    # degraded mode on this host
         assert r0.device_index is r1.device_index
         assert r0.device_index.out_hub.shape[0] == rs.hi - rs.lo
         assert r0.device_index.row_lo == rs.lo
@@ -266,8 +264,6 @@ def test_replicas_share_windowed_device_layout():
     svc.hot_swap()
     for rs, old in zip(svc.shards, gen_layouts):
         r0, r1 = rs.replicas
-        if r0.device_index is None:
-            continue
         assert r0.device_index is r1.device_index   # still shared ...
         assert r0.device_index is not old           # ... but rebuilt
 
@@ -321,3 +317,50 @@ def test_sharded_stats_per_shard_breakdown():
     # nested executor shape: latencies and traffic live together
     assert set(st_["executor"]) >= {"local", "remote", "sub_batches",
                                     "digest_bytes"}
+
+
+# ------------------------------------------------------------------ #
+# Device faults surface (no silent host fallback)
+# ------------------------------------------------------------------ #
+def test_sharded_device_layout_failure_raises(monkeypatch):
+    from repro.core.device_index import DeviceIndex
+
+    def boom(*a, **kw):
+        raise RuntimeError("no device memory")
+
+    g = erdos_renyi(40, 3.0, 3, seed=3)
+    monkeypatch.setattr(DeviceIndex, "from_frozen", boom)
+    with pytest.raises(RuntimeError, match="no device memory"):
+        ShardedRLCService.build(g, ShardedServiceConfig(k=2, num_shards=2))
+
+
+def test_pin_raises_when_placement_fails():
+    from repro.service.sharded.replica import _pin
+
+    g = erdos_renyi(20, 3.0, 2, seed=4)
+    _, ids, frozen = _frozen(g)
+    from repro.core.device_index import DeviceIndex
+    layout = DeviceIndex.from_frozen(frozen, ids)
+    assert _pin(layout, None) is layout
+    with pytest.raises(Exception):
+        _pin(layout, object())          # not a device
+
+
+def test_cross_shard_device_join_failure_raises(monkeypatch):
+    """An in-process cross-shard device join that fails raises; it does
+    not fall back to the host numpy join or to BiBFS."""
+    from repro.service.sharded import fanout
+
+    g = erdos_renyi(40, 3.5, 3, seed=5)
+    svc = ShardedRLCService.build(
+        g, ShardedServiceConfig(k=2, batch_size=8, cache_capacity=0,
+                                num_shards=2))
+
+    def boom(*a, **kw):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(fanout.ScatterGatherExecutor, "_join_device", boom)
+    lo, hi = svc.plan.range(1)
+    with pytest.raises(RuntimeError, match="device lost"):
+        svc.query_batch([(0, lo, (0,))])
+    assert svc.fanout.remote_joins_numpy == 0 and svc.fanout.degraded == 0

@@ -1,0 +1,25 @@
+"""Published peaks of each accelerator, keyed by JAX's ``device_kind``.
+
+A device that is not in the table is an error: a roofline share needs
+the peak of the chip it ran on, and a guessed peak would make it wrong.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": dict(
+        hbm_bytes_per_s=819e9,
+        bf16_flops_per_s=197e12,
+        int8_ops_per_s=393e12,
+        hbm_bytes=16e9,
+        source="Google Cloud documentation, 'TPU v5e'",
+    ),
+}
+
+
+def peak(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; add its "
+            f"row, with the source, to bench/lib/peaks.py") from None

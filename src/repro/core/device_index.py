@@ -111,9 +111,22 @@ class DeviceIndex:
     def query_batch(self, s: np.ndarray, t: np.ndarray, mr: np.ndarray,
                     use_pallas: bool = False,
                     method: str = "dense") -> np.ndarray:
-        s = jnp.asarray(s, jnp.int32)
-        t = jnp.asarray(t, jnp.int32)
-        mr = jnp.asarray(mr, jnp.int32)
+        """Answer a batch on the device and read the answers back: the
+        synchronous composition of :meth:`inputs` and :meth:`join`."""
+        return np.asarray(self.join(*self.inputs(s, t, mr),
+                                    use_pallas=use_pallas, method=method))
+
+    @staticmethod
+    def inputs(s: np.ndarray, t: np.ndarray, mr: np.ndarray
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """The batch's ``(s, t, mr)`` as int32 device arrays."""
+        return (jnp.asarray(s, jnp.int32), jnp.asarray(t, jnp.int32),
+                jnp.asarray(mr, jnp.int32))
+
+    def join(self, s: jax.Array, t: jax.Array, mr: jax.Array,
+             use_pallas: bool = False, method: str = "dense") -> jax.Array:
+        """Dispatch the join over device inputs; returns the (possibly
+        not yet ready) boolean answers."""
         if use_pallas:
             from repro.kernels import ops
             out = ops.mergejoin_query(
@@ -127,7 +140,7 @@ class DeviceIndex:
             out = _query_batch_rows(self.out_hub, self.out_mr, self.in_hub,
                                     self.in_mr, s - self.row_lo,
                                     t - self.row_lo, s, t, mr)
-        return np.asarray(out)
+        return out
 
     def query(self, s: int, t: int, L: Sequence[int]) -> bool:
         c = self.mr_ids.get(tuple(L))

@@ -12,7 +12,8 @@ ad-hoc ``stats()`` dicts:
 * :mod:`repro.obs.tracing` — sampling-controlled per-query span tracing
   (parse -> cache probe -> queue wait -> shard route -> digest hand-off
   -> executor backend) with a Chrome ``trace_event``
-  exporter.
+  exporter, and the always-on phase spans (``rlc_span_seconds`` plus a
+  ``rlc:<phase>`` profiler annotation on the device trace's clock).
 * :mod:`repro.obs.export` — a versioned JSON snapshot schema (asserted
   by ``tests/test_obs.py`` and validated by the benchmark smoke run)
   plus a Prometheus text-format dump.
@@ -36,7 +37,7 @@ See ``src/repro/obs/README.md`` for the metric taxonomy.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Dict, Optional
 
 from .audit import (AUDIT_SCHEMA, audit_index, bank_audit_metrics,
                     fingerprint, validate_audit_report)
@@ -48,13 +49,15 @@ from .export import (SCHEMA, snapshot, snapshot_to_prometheus,
 from .metrics import (NULL_REGISTRY, Counter, Gauge, Histogram, Metric,
                       MetricsRegistry, NullRegistry, Reservoir)
 from .shadow import ShadowVerifier, attach_shadow
-from .tracing import SpanEvent, Trace, Tracer, span_tree
+from .tracing import (NULL_PHASE, PhaseSpan, SpanEvent, Trace, Tracer,
+                      span_tree)
 
 __all__ = [
     "AUDIT_SCHEMA", "SCHEMA", "WITNESS_SCHEMA", "BuildPhaseObserver",
     "Counter", "Gauge", "Histogram", "Metric", "MetricsRegistry",
-    "NullRegistry", "NULL_REGISTRY", "Observability", "NULL_OBS",
-    "Reservoir", "ShadowVerifier", "SpanEvent", "Trace", "Tracer",
+    "NullRegistry", "NULL_PHASE", "NULL_REGISTRY", "Observability",
+    "NULL_OBS", "PhaseSpan", "Reservoir", "ShadowVerifier", "SpanEvent",
+    "Trace", "Tracer",
     "attach_shadow", "audit_index", "bank_audit_metrics",
     "build_witness", "explain_rows", "fingerprint", "replay_witness",
     "snapshot", "snapshot_to_prometheus", "span_tree", "to_prometheus",
@@ -68,8 +71,8 @@ class Observability:
 
     ``enabled=False`` swaps in the null registry and a zero-rate tracer
     so every instrumented call site stays branch-free and near-free.
-    Counters/histograms are default-on; span tracing only activates at
-    ``trace_sample_rate > 0``.
+    Counters/histograms and phase spans are default-on; span tracing
+    only activates at ``trace_sample_rate > 0``.
     """
 
     def __init__(self, enabled: bool = True,
@@ -85,6 +88,22 @@ class Observability:
             self.registry = NULL_REGISTRY
             self.tracer = Tracer(sample_rate=0.0, max_events=0)
         self._build_observer: Optional[BuildPhaseObserver] = None
+        self._phases: Dict[str, PhaseSpan] = {}
+
+    def phase(self, name: str, cat: str = ""):
+        """The :class:`PhaseSpan` named ``name``, one per name (call
+        sites bind it at construction); :data:`NULL_PHASE` when
+        disabled."""
+        if not self.enabled:
+            return NULL_PHASE
+        ph = self._phases.get(name)
+        if ph is None:
+            cell = self.registry.histogram(
+                "rlc_span_seconds",
+                desc="wall time of one entry of a serving-path phase",
+                unit="s", labelnames=("span",)).labels(span=name)
+            ph = self._phases[name] = PhaseSpan(name, cell, cat)
+        return ph
 
     # ------------------------------------------------------------------ #
     def build_observer(self, context: str = "full") -> \

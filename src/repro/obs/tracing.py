@@ -16,15 +16,26 @@ programmatic analysis.
 The event buffer is bounded: past ``max_events`` new spans are dropped
 and counted (``tracer.dropped``) — tracing must never become the memory
 leak it exists to diagnose.
+
+A :class:`PhaseSpan` is the always-on counterpart: one named phase of
+the serving path (admission, the device join's transfer, dispatch, wait
+and readback, ...), bound once per name. Every entry adds its seconds to
+the ``rlc_span_seconds{span=<name>}`` histogram, opens a
+``jax.profiler.TraceAnnotation`` named ``rlc:<name>`` while a profiler
+session records (so the phase lands on the profiler's host line, on the
+device trace's clock), and, given a sampled :class:`Trace`, records the
+same span into that trace's buffer.
 """
 from __future__ import annotations
 
 import random
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
-__all__ = ["SpanEvent", "Trace", "Tracer", "span_tree"]
+__all__ = ["NULL_PHASE", "PhaseSpan", "SpanEvent", "Trace", "Tracer",
+           "span_tree"]
 
 
 @dataclass(frozen=True)
@@ -179,6 +190,118 @@ class Tracer:
                     skipped=self.traces_skipped,
                     events=len(self.events),
                     dropped=self.dropped)
+
+
+# --------------------------------------------------------------------- #
+#: ``jax.profiler.TraceAnnotation`` once JAX's profiler is loaded; no
+#: profiler session can record before that, and this module never loads
+#: JAX itself (jax-free worker processes bind phases too)
+_annotation = None
+
+
+def _profiler_annotation():
+    global _annotation
+    if _annotation is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is not None:
+            _annotation = prof.TraceAnnotation
+    return _annotation
+
+
+class PhaseSpan:
+    """One named phase of the serving path, bound once per name.
+
+    ``with phase(trace, **args): ...`` times the body into its
+    ``rlc_span_seconds`` cell, annotates it as ``rlc:<name>`` for an
+    active profiler session (skipped at the cost of one check when none
+    records), and records a ``name`` span into ``trace`` when the unit of
+    work is sampled. The phase object holds no per-entry state, so one
+    instance serves every thread.
+    """
+
+    __slots__ = ("name", "annotation", "cat", "cell")
+
+    def __init__(self, name: str, cell, cat: str = ""):
+        self.name = name
+        self.annotation = f"rlc:{name}"
+        self.cat = cat
+        self.cell = cell
+
+    def __call__(self, trace: Optional[Trace] = None,
+                 **args) -> "_PhaseCtx":
+        return _PhaseCtx(self, trace, args)
+
+
+class _PhaseCtx:
+    """One entry of a :class:`PhaseSpan`; ``close()`` is idempotent, for
+    phases that end before a block does (admission runs)."""
+
+    __slots__ = ("_phase", "_trace", "_args", "_ann", "_ts", "_t0")
+
+    def __init__(self, phase: PhaseSpan, trace: Optional[Trace], args):
+        self._phase = phase
+        self._trace = trace
+        self._args = args
+        self._t0 = None
+
+    def open(self) -> "_PhaseCtx":
+        ann = _annotation or _profiler_annotation()
+        if ann is not None and ann.is_enabled():
+            self._ann = ann(self._phase.annotation)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        if self._trace is not None:
+            self._ts = self._trace.tracer._now()
+        self._t0 = time.perf_counter()
+        return self
+
+    def close(self, error: Optional[str] = None) -> None:
+        if self._t0 is None:
+            return
+        self._phase.cell.observe(time.perf_counter() - self._t0)
+        self._t0 = None
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        tr = self._trace
+        if tr is not None:
+            args = self._args
+            if error is not None:
+                args = dict(args, error=error)
+            ph = self._phase
+            tr.tracer._emit(SpanEvent(ph.name, ph.cat, tr.tid, self._ts,
+                                      tr.tracer._now() - self._ts,
+                                      args or None))
+
+    __enter__ = open
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.close(exc_type.__name__ if exc_type is not None else None)
+        return False
+
+
+class _NullPhase:
+    """The phase of a disabled :class:`~repro.obs.Observability`: every
+    entry is a no-op."""
+
+    __slots__ = ()
+
+    def __call__(self, trace=None, **args) -> "_NullPhase":
+        return self
+
+    def open(self) -> "_NullPhase":
+        return self
+
+    def close(self, error=None) -> None:
+        pass
+
+    __enter__ = open
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        return False
+
+
+NULL_PHASE = _NullPhase()
 
 
 # --------------------------------------------------------------------- #

@@ -19,11 +19,16 @@ host backend. Per-backend latency/throughput lands in
 :class:`repro.service.metrics.LatencyRecorder` and — when an
 :class:`repro.obs.Observability` is attached — in the shared metrics
 registry (labeled by backend and shard), with a span per batch when the
-batch rides a sampled trace.
+batch rides a sampled trace. The device backends run in four phase
+spans (:meth:`repro.obs.Observability.phase`): ``exec.h2d`` (pad and
+host-to-device inputs), ``exec.dispatch`` (the jitted join call and the
+request for its answers), ``exec.wait`` (until the answers are ready)
+and ``exec.d2h`` (the rest of the readback).
 """
 from __future__ import annotations
 
 import time
+from contextlib import nullcontext
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
@@ -88,6 +93,9 @@ class BatchExecutor:
             desc="batches not answered by the first-choice backend",
             labelnames=("from", "to", "shard"))
         self._shard = shard
+        self._ph_h2d, self._ph_dispatch, self._ph_wait, self._ph_d2h = (
+            self.obs.phase(f"exec.{p}", cat="executor")
+            for p in ("h2d", "dispatch", "wait", "d2h"))
 
     # ------------------------------------------------------------------ #
     def available(self, backend: str) -> bool:
@@ -126,7 +134,8 @@ class BatchExecutor:
         fallback). A backend that raises is not retried elsewhere: the
         error propagates as :class:`ExecutorError`. ``trace``: optional
         :class:`repro.obs.Trace`; the batch gets an ``exec:<backend>``
-        span.
+        span (``args.error`` on a failed batch), the device phases nested
+        inside it.
         """
         first = self.resolve(backend)
         b = next((c for c in (first,) + BACKENDS if self.available(c)),
@@ -134,14 +143,14 @@ class BatchExecutor:
         if b is None:
             raise ExecutorError(f"no backend available for {first!r}")
         n = len(s) if n_real is None else int(n_real)
+        span = (trace.span(f"exec:{b}", cat="executor", n=n,
+                           fallback=b != first)
+                if trace is not None else nullcontext())
         t0 = time.perf_counter()
         try:
-            ans = self._run(b, s, t, mr_id, n)
+            with span:
+                ans = self._run(b, s, t, mr_id, n, trace)
         except Exception as e:
-            if trace is not None:
-                dt = time.perf_counter() - t0
-                trace.add(f"exec:{b}", trace.tracer._now() - dt, dt,
-                          cat="executor", error=type(e).__name__)
             raise ExecutorError(
                 f"backend {b!r} failed on a batch of {n} queries") from e
         dt = time.perf_counter() - t0
@@ -149,9 +158,6 @@ class BatchExecutor:
         self._m_lat[b].observe(dt)
         self._m_bat[b].inc()
         self._m_qry[b].inc(n)
-        if trace is not None:
-            trace.add(f"exec:{b}", trace.tracer._now() - dt, dt,
-                      cat="executor", n=n, fallback=b != first)
         if b != first:
             self.fallbacks += 1
             self._m_fallback.labels(
@@ -207,17 +213,26 @@ class BatchExecutor:
             [np.asarray(a[:n]), np.full(cap - n, a[0], dtype=a.dtype)])
         return pad(s), pad(t), pad(mr_id)
 
-    def _run(self, backend: str, s, t, mr_id, n: int) -> np.ndarray:
+    def _run(self, backend: str, s, t, mr_id, n: int,
+             trace=None) -> np.ndarray:
         # The device backends get pow2-padded shapes (static jit set);
         # the per-query loop backends run exactly the real slots.
-        if backend == "pallas":
-            s, t, mr_id = self._pad_pow2(s, t, mr_id, n)
-            return self.device_index.query_batch(s, t, mr_id,
-                                                 use_pallas=True)
-        if backend == "sorted":
-            s, t, mr_id = self._pad_pow2(s, t, mr_id, n)
-            return self.device_index.query_batch(s, t, mr_id,
-                                                 method="sorted")
+        if backend in ("pallas", "sorted"):
+            di = self.device_index
+            with self._ph_h2d(trace):
+                args = di.inputs(*self._pad_pow2(s, t, mr_id, n))
+            with self._ph_dispatch(trace):
+                out = (di.join(*args, use_pallas=True)
+                       if backend == "pallas"
+                       else di.join(*args, method="sorted"))
+                # queue the readback behind the join now, as a bare
+                # np.asarray would: waiting first and only then asking
+                # for the answers costs a second round trip to the device
+                out.copy_to_host_async()
+            with self._ph_wait(trace):
+                out.block_until_ready()
+            with self._ph_d2h(trace):
+                return np.asarray(out)
         if backend == "numpy":
             return self.frozen.query_batch(s[:n], t[:n], mr_id[:n])
         if backend == "python":

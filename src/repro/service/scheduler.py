@@ -18,9 +18,7 @@ Flushed batches carry exactly their real requests — underfull deadline
 flushes are *not* padded to ``batch_size`` (repeating the first request
 used to burn executor slots on every deadline flush; the executor now
 pads to a power-of-two internally for the jit backends, which bounds the
-number of compiled shapes without recomputing duplicate slots). The
-``rlc_batcher_padding_ratio`` histogram records padded/total slots per
-flush so the waste stays provably gone.
+number of compiled shapes without recomputing duplicate slots).
 
 Duplicate in-flight keys are *coalesced*: submitting a ``(s, t, mr_id)``
 already queued returns the queued :class:`Request` instead of occupying a
@@ -142,15 +140,11 @@ class MicroBatcher:
             labelnames=("reason",))
         self._m_fill = {r: fill.labels(reason=r)
                         for r in ("full", "deadline", "drain")}
-        self._m_padding = reg.histogram(
-            "rlc_batcher_padding_ratio",
-            desc="padded slots / total slots per flushed batch "
-                 "(0 since underfull flushes stopped padding)",
-            unit="1").labels()
         self._m_evicted = reg.counter(
             "rlc_batcher_evicted",
             desc="queued requests evicted pre-flush by admission "
                  "control").labels()
+        self._ph_tick = self.obs.phase("tick", cat="batcher")
 
     # ------------------------------------------------------------------ #
     def params(self, mr_len: int) -> Tuple[int, float]:
@@ -280,7 +274,8 @@ class MicroBatcher:
         happens to submit or poll; with it, an underfull bucket flushes at
         most ~``interval_s`` after its deadline even if no admission ever
         arrives again. ``on_batch`` runs on the ticker thread for every
-        flushed batch (execute + backfill caches there). Off by default.
+        flushed batch (execute + backfill caches there), each tick that
+        flushes inside one ``tick`` phase span. Off by default.
 
         ``on_error`` (optional) is invoked with the exception when
         ``on_batch`` raises — async callers use it to fail pending
@@ -293,18 +288,23 @@ class MicroBatcher:
 
         def loop():
             while not self._ticker_stop.wait(interval_s):
-                for batch in self.poll():
-                    try:
-                        on_batch(batch)
-                    except Exception as exc:
-                        # a failing callback must not kill the ticker —
-                        # later deadline flushes still have to fire
-                        self.ticker_errors += 1
-                        if on_error is not None:
-                            try:
-                                on_error(exc)
-                            except Exception:
-                                self.ticker_errors += 1
+                batches = self.poll()
+                if not batches:
+                    continue
+                with self._ph_tick():
+                    for batch in batches:
+                        try:
+                            on_batch(batch)
+                        except Exception as exc:
+                            # a failing callback must not kill the
+                            # ticker — later deadline flushes still have
+                            # to fire
+                            self.ticker_errors += 1
+                            if on_error is not None:
+                                try:
+                                    on_error(exc)
+                                except Exception:
+                                    self.ticker_errors += 1
 
         with self._lock:
             if self._ticker is not None:
@@ -349,7 +349,6 @@ class MicroBatcher:
         for r in reqs:
             wait_cell.observe(now - r.enqueued_at)
         # real slots only — the executor pads jit backends internally
-        self._m_padding.observe(0.0)
         n = len(reqs)
         s = np.fromiter((r.s for r in reqs), np.int32, n)
         t = np.fromiter((r.t for r in reqs), np.int32, n)

@@ -13,6 +13,11 @@ cache, and — on miss — handed to the micro-batcher. Flushed batches run on
 the executor (device backend with python fallback); answers backfill the
 cache. ``query_batch`` is synchronous: it drains the scheduler before
 returning, so every admitted query is answered in admission order.
+
+Phase spans (:meth:`repro.obs.Observability.phase`, always on): the
+whole call (``query_batch``), each run of admissions between executed
+batches (``admit``), each batch end to end (``execute``) and, inside it,
+the controller update and answer fan-out after the join (``answer``).
 """
 from __future__ import annotations
 
@@ -95,6 +100,16 @@ class ServiceConfig:
     clock: Optional[Callable[[], float]] = None
 
 
+def bind_phases(svc) -> None:
+    """Bind the facade's phase spans on ``svc`` (both facades share the
+    admission loop that enters them)."""
+    ph = svc.obs.phase
+    svc._ph_query_batch = ph("query_batch", cat="service")
+    svc._ph_admit = ph("admit", cat="admission")
+    svc._ph_execute = ph("execute", cat="service")
+    svc._ph_answer = ph("answer", cat="service")
+
+
 class RLCService:
     def __init__(self, graph: LabeledGraph, index: RLCIndex,
                  config: ServiceConfig,
@@ -145,6 +160,7 @@ class RLCService:
             "rlc_explain_requests",
             desc="EXPLAIN bundles produced, by witness kind",
             labelnames=("kind",))
+        bind_phases(self)
         from repro.obs.shadow import attach_shadow
         self._shadow = attach_shadow(self)
 
@@ -222,71 +238,83 @@ class RLCService:
         method bridges through the engine instead of draining the
         batcher itself — same answers, no lost-flush race.
         """
-        if self._engine is not None and self._engine.active:
-            futures = [self.submit(s, t, c) for (s, t, c) in queries]
-            self._engine.flush()
-            return [f.result(timeout=60.0) for f in futures]
-        answers: List[Optional[Answer]] = [None] * len(queries)
-        # canonical (s, t, mr_id) per position, kept only when the shadow
-        # verifier wants to sample answered queries afterwards
-        keys: Optional[List[Tuple[int, int, int]]] = (
-            [None] * len(queries) if self._shadow is not None else None)
-        # scheduler req_id -> output positions (> 1 when duplicate in-flight
-        # queries were coalesced onto one request)
-        slot: Dict[int, List[int]] = {}
-        # one sampled trace per query_batch call; None on the unsampled
-        # hot path, so every span below is a single comparison away
-        tr = self.obs.tracer.maybe_trace()
-        admission = self.ctl.admission
-        for i, (s, t, constraint) in enumerate(queries):
-            t0 = tr.tracer._now() if tr is not None else 0.0
-            s, t, mr_id, mr_len = self._admit(s, t, constraint)
-            if keys is not None:
-                keys[i] = (s, t, mr_id)
-            # the frequency sketch counts every arrival (hits included):
-            # key popularity is a property of the workload, not of the
-            # cache's current contents
-            self.ctl.observe_admit((s, t, mr_id), mr_len)
-            hit = self.cache.get((s, t, mr_id), mr_len=mr_len)
-            if tr is not None:
-                tr.add(f"admit[{i}]", t0, tr.tracer._now() - t0,
-                       cat="admission", mr_len=mr_len,
-                       cache="hit" if hit is not None else "miss")
-            if hit is not None:
-                answers[i] = Answer(hit, "cache_hit")
-                continue
-            if admission is not None:
-                decision, victim = admission.decide(
-                    (s, t, mr_id), mr_len, self.batcher)
-                if decision == "shed":
-                    answers[i] = SHED
-                    continue
-                if decision == "evict" and self.batcher.evict(victim):
-                    # the victim's submitters get the explicit SHED
-                    for pos in slot.pop(victim.req_id, ()):
-                        answers[pos] = SHED
-            req, ready = self.batcher.submit(s, t, mr_id, mr_len, now)
-            slot.setdefault(req.req_id, []).append(i)
-            for batch in ready:
+        with self._ph_query_batch():
+            if self._engine is not None and self._engine.active:
+                futures = [self.submit(s, t, c) for (s, t, c) in queries]
+                self._engine.flush()
+                return [f.result(timeout=60.0) for f in futures]
+            answers: List[Optional[Answer]] = [None] * len(queries)
+            # canonical (s, t, mr_id) per position, kept only when the
+            # shadow verifier wants to sample answered queries afterwards
+            keys: Optional[List[Tuple[int, int, int]]] = (
+                [None] * len(queries) if self._shadow is not None else None)
+            # scheduler req_id -> output positions (> 1 when duplicate
+            # in-flight queries were coalesced onto one request)
+            slot: Dict[int, List[int]] = {}
+            # one sampled trace per query_batch call; None on the unsampled
+            # hot path, so every span below is a single comparison away
+            tr = self.obs.tracer.maybe_trace()
+            admission = self.ctl.admission
+            # one admit span per run of admissions: it closes while a batch
+            # executes and reopens after
+            admit = self._ph_admit().open()
+            try:
+                for i, (s, t, constraint) in enumerate(queries):
+                    t0 = tr.tracer._now() if tr is not None else 0.0
+                    s, t, mr_id, mr_len = self._admit(s, t, constraint)
+                    if keys is not None:
+                        keys[i] = (s, t, mr_id)
+                    # the frequency sketch counts every arrival (hits
+                    # included): key popularity is a property of the
+                    # workload, not of the cache's current contents
+                    self.ctl.observe_admit((s, t, mr_id), mr_len)
+                    hit = self.cache.get((s, t, mr_id), mr_len=mr_len)
+                    if tr is not None:
+                        tr.add(f"admit[{i}]", t0, tr.tracer._now() - t0,
+                               cat="admission", mr_len=mr_len,
+                               cache="hit" if hit is not None else "miss")
+                    if hit is not None:
+                        answers[i] = Answer(hit, "cache_hit")
+                        continue
+                    if admission is not None:
+                        decision, victim = admission.decide(
+                            (s, t, mr_id), mr_len, self.batcher)
+                        if decision == "shed":
+                            answers[i] = SHED
+                            continue
+                        if (decision == "evict"
+                                and self.batcher.evict(victim)):
+                            # the victim's submitters get the explicit SHED
+                            for pos in slot.pop(victim.req_id, ()):
+                                answers[pos] = SHED
+                    req, ready = self.batcher.submit(s, t, mr_id, mr_len,
+                                                     now)
+                    slot.setdefault(req.req_id, []).append(i)
+                    if ready:
+                        admit.close()
+                        for batch in ready:
+                            self._execute(batch, answers, slot, tr)
+                        admit.open()
+            finally:
+                admit.close()
+            for batch in self.batcher.drain():
                 self._execute(batch, answers, slot, tr)
-        for batch in self.batcher.drain():
-            self._execute(batch, answers, slot, tr)
-        if any(a is None for a in answers):
-            # a batch was flushed outside this call (ticker thread or a
-            # concurrent query_batch stealing a coalesced key) — fail loud
-            # rather than coerce the hole to False
-            raise RuntimeError(
-                "query_batch lost answers to an external flush; do not "
-                "share a ticker-driven or concurrent MicroBatcher with "
-                "synchronous query_batch")
-        self.queries_served += len(queries)
-        out: List[Answer] = answers
-        self.queries_shed += sum(1 for a in out if a.shed)
-        if keys is not None:
-            for (s, t, mr_id), ans in zip(keys, out):
-                if not ans.shed:        # no answer to verify
-                    self._shadow.offer(s, t, mr_id, ans.value)
-        return out
+            if any(a is None for a in answers):
+                # a batch was flushed outside this call (ticker thread or
+                # a concurrent query_batch stealing a coalesced key) —
+                # fail loud rather than coerce the hole to False
+                raise RuntimeError(
+                    "query_batch lost answers to an external flush; do not "
+                    "share a ticker-driven or concurrent MicroBatcher with "
+                    "synchronous query_batch")
+            self.queries_served += len(queries)
+            out: List[Answer] = answers
+            self.queries_shed += sum(1 for a in out if a.shed)
+            if keys is not None:
+                for (s, t, mr_id), ans in zip(keys, out):
+                    if not ans.shed:        # no answer to verify
+                        self._shadow.offer(s, t, mr_id, ans.value)
+            return out
 
     def _run_batch(self, batch: Batch, tr=None):
         """Produce one answer per real request, plus per-request backend
@@ -313,7 +341,6 @@ class RLCService:
 
     def _execute(self, batch: Batch, answers: List[Optional[Answer]],
                  slot: Dict[int, List[int]], tr=None) -> None:
-        t0 = time.perf_counter()
         if tr is not None:
             # queue wait is measured on the scheduler's clock; only the
             # duration crosses into the tracer's timeline
@@ -322,28 +349,28 @@ class RLCService:
                               max(batch.flushed_at - oldest, 0.0),
                               cat="batcher", reason=batch.reason,
                               mr_len=batch.mr_len, n=batch.n_real)
-            with tr.span("execute", cat="service",
-                         n=batch.n_real, mr_len=batch.mr_len):
-                vals, backends = self._run_batch(batch, tr)
-        else:
-            vals, backends = self._run_batch(batch)
-        exec_s = time.perf_counter() - t0
-        # feed the control loops (SLO EWMAs, back-pressure queue waits);
-        # a VirtualClock scheduler clock also advances by the measured
-        # execute time so open-loop replay accumulates realistic waits
-        self.ctl.on_batch_executed(batch, exec_s)
-        advance = getattr(self.batcher.clock, "advance", None)
-        if advance is not None:
-            advance(exec_s)
-        for req, val, backend in zip(batch.requests, vals, backends):
-            val = bool(val)
-            self.cache.put((req.s, req.t, req.mr_id), val,
-                           mr_len=batch.mr_len)
-            ans = Answer(val,
-                         "degraded" if backend == "bibfs" else "computed",
-                         backend)
-            for pos in slot.get(req.req_id, ()):
-                answers[pos] = ans
+        with self._ph_execute(tr, n=batch.n_real, mr_len=batch.mr_len):
+            t0 = time.perf_counter()
+            vals, backends = self._run_batch(batch, tr)
+            exec_s = time.perf_counter() - t0
+            with self._ph_answer(tr):
+                # feed the control loops (SLO EWMAs, back-pressure queue
+                # waits); a VirtualClock scheduler clock also advances by
+                # the measured execute time so open-loop replay
+                # accumulates realistic waits
+                self.ctl.on_batch_executed(batch, exec_s)
+                advance = getattr(self.batcher.clock, "advance", None)
+                if advance is not None:
+                    advance(exec_s)
+                for req, val, backend in zip(batch.requests, vals,
+                                             backends):
+                    val = bool(val)
+                    self.cache.put((req.s, req.t, req.mr_id), val,
+                                   mr_len=batch.mr_len)
+                    ans = Answer(val, "degraded" if backend == "bibfs"
+                                 else "computed", backend)
+                    for pos in slot.get(req.req_id, ()):
+                        answers[pos] = ans
 
     # -- EXPLAIN / provenance -------------------------------------------- #
     def explain(self, s: int, t: int, constraint: Constraint,

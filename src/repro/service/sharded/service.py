@@ -32,7 +32,7 @@ from repro.obs import Observability
 from ..cache import ResultCache
 from ..control import ControlPlane
 from ..scheduler import Batch, MicroBatcher
-from ..service import RLCService, ServiceConfig
+from ..service import RLCService, ServiceConfig, bind_phases
 from .fanout import ScatterGatherExecutor
 from .plan import ShardPlan, plan_shards
 from .replica import ShardReplicaSet, build_device_layout, build_replica
@@ -149,6 +149,7 @@ class ShardedRLCService:
             "rlc_explain_requests",
             desc="EXPLAIN bundles produced, by witness kind",
             labelnames=("kind",))
+        bind_phases(self)
         from repro.obs.shadow import attach_shadow
         self._shadow = attach_shadow(self)
 

@@ -159,13 +159,9 @@ def test_slo_controller_rejects_bad_target():
 # --------------------------------------------------------------------- #
 # scheduler: no padding, eviction, priority scans
 # --------------------------------------------------------------------- #
-def test_flush_carries_real_slots_only_and_padding_ratio_is_zero():
-    reg = MetricsRegistry()
-
-    class Obs:
-        registry = reg
+def test_flush_carries_real_slots_only():
     clock = [0.0]
-    b = MicroBatcher(8, 0.5, clock=lambda: clock[0], obs=Obs())
+    b = MicroBatcher(8, 0.5, clock=lambda: clock[0])
     b.submit(0, 1, 0, 1)
     b.submit(2, 3, 0, 1)
     clock[0] = 1.0
@@ -173,9 +169,6 @@ def test_flush_carries_real_slots_only_and_padding_ratio_is_zero():
     assert len(ready) == 1
     assert len(ready[0].s) == 2 == ready[0].n_real
     assert ready[0].n_padding == 0
-    m = reg.get("rlc_batcher_padding_ratio")
-    (_key, cell), = m.series()
-    assert cell.reservoir.count == 1 and cell.reservoir.vmax == 0.0
 
 
 def test_evict_removes_queued_request():

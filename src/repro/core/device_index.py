@@ -10,6 +10,7 @@ Row padding uses hub id ``-1`` (never matches a real hub / query vertex).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -32,6 +33,11 @@ class DeviceIndex:
       * sorted — rows re-encoded as ascending ``hub * C + mr`` keys; the
         join is a vectorized ``searchsorted`` intersection, moving (Q, E)
         instead of (Q, E, E) through HBM — the XLA-lowered serving path.
+
+    A batch reaches the device packed: :meth:`join` takes one ``(3, Q)``
+    int32 host array (rows ``s``, ``t``, ``mr``) and hands it to one
+    jitted entry that slices it on the device, so a batch costs one
+    host-to-device transfer, made by the jitted call itself.
 
     With ``row_lo > 0`` the arrays hold only the vertex-row window
     ``[row_lo, row_lo + rows)`` (a shard's slice): query/hub *ids* stay
@@ -112,35 +118,21 @@ class DeviceIndex:
                     use_pallas: bool = False,
                     method: str = "dense") -> np.ndarray:
         """Answer a batch on the device and read the answers back: the
-        synchronous composition of :meth:`inputs` and :meth:`join`."""
-        return np.asarray(self.join(*self.inputs(s, t, mr),
-                                    use_pallas=use_pallas, method=method))
+        synchronous form of :meth:`join`."""
+        q = np.stack([s, t, mr]).astype(np.int32)
+        return np.asarray(self.join(q, "pallas" if use_pallas else method))
 
-    @staticmethod
-    def inputs(s: np.ndarray, t: np.ndarray, mr: np.ndarray
-               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        """The batch's ``(s, t, mr)`` as int32 device arrays."""
-        return (jnp.asarray(s, jnp.int32), jnp.asarray(t, jnp.int32),
-                jnp.asarray(mr, jnp.int32))
-
-    def join(self, s: jax.Array, t: jax.Array, mr: jax.Array,
-             use_pallas: bool = False, method: str = "dense") -> jax.Array:
-        """Dispatch the join over device inputs; returns the (possibly
-        not yet ready) boolean answers."""
-        if use_pallas:
-            from repro.kernels import ops
-            out = ops.mergejoin_query(
-                self.out_hub, self.out_mr, self.in_hub, self.in_mr,
-                s, t, mr, row_base_out=self.row_lo, row_base_in=self.row_lo)
-        elif method == "sorted":
-            out = _query_batch_sorted_rows(
-                self.out_key, self.in_key, s - self.row_lo,
-                t - self.row_lo, s, t, mr, self.num_mrs)
-        else:
-            out = _query_batch_rows(self.out_hub, self.out_mr, self.in_hub,
-                                    self.in_mr, s - self.row_lo,
-                                    t - self.row_lo, s, t, mr)
-        return out
+    def join(self, q: np.ndarray, method: str = "dense") -> jax.Array:
+        """Dispatch the join of a packed ``(3, Q)`` int32 host batch
+        (rows ``s``, ``t``, ``mr``); returns the (possibly not yet ready)
+        boolean answers. The batch reaches the device as the jitted
+        call's one host argument: no separate transfer per input.
+        ``method``: ``"pallas"`` (the merge-join kernel), ``"sorted"``
+        or ``"dense"``."""
+        return _join_packed(self.out_hub, self.out_mr, self.in_hub,
+                            self.in_mr, self.out_key, self.in_key, q,
+                            row_lo=self.row_lo, num_mrs=self.num_mrs,
+                            method=method)
 
     def query(self, s: int, t: int, L: Sequence[int]) -> bool:
         c = self.mr_ids.get(tuple(L))
@@ -181,6 +173,27 @@ class DeviceIndex:
     def gather_in_rows(self, t: np.ndarray) -> Tuple[jax.Array, jax.Array]:
         t = jnp.asarray(t, jnp.int32) - self.row_lo
         return self.in_hub[t], self.in_mr[t]
+
+
+@functools.partial(jax.jit, static_argnames=("row_lo", "num_mrs",
+                                             "method"))
+def _join_packed(out_hub, out_mr, in_hub, in_mr, out_key, in_key, q, *,
+                 row_lo: int, num_mrs: int, method: str):
+    """The serving join as one jit boundary: ``q`` is the packed
+    ``(3, Q)`` batch, sliced into ``s``, ``t``, ``mr`` on the device;
+    ``row_lo`` offsets the storage rows of a row-windowed layout."""
+    s, t, mr = q[0], q[1], q[2]
+    if method == "pallas":
+        from repro.kernels import ops
+        return ops.mergejoin_query(out_hub, out_mr, in_hub, in_mr, s, t, mr,
+                                   row_base_out=row_lo, row_base_in=row_lo)
+    if method == "sorted":
+        return _query_batch_sorted_rows(out_key, in_key, s - row_lo,
+                                        t - row_lo, s, t, mr, num_mrs)
+    if method == "dense":
+        return _query_batch_rows(out_hub, out_mr, in_hub, in_mr,
+                                 s - row_lo, t - row_lo, s, t, mr)
+    raise ValueError(f"unknown join method {method!r}")
 
 
 @jax.jit

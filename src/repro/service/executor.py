@@ -21,9 +21,11 @@ host backend. Per-backend latency/throughput lands in
 registry (labeled by backend and shard), with a span per batch when the
 batch rides a sampled trace. The device backends run in four phase
 spans (:meth:`repro.obs.Observability.phase`): ``exec.h2d`` (pad and
-host-to-device inputs), ``exec.dispatch`` (the jitted join call and the
-request for its answers), ``exec.wait`` (until the answers are ready)
-and ``exec.d2h`` (the rest of the readback).
+stack the inputs into one ``(3, cap)`` int32 host array),
+``exec.dispatch`` (the jitted join call, which transfers that array,
+and the request for its answers), ``exec.wait`` (until the answers are
+ready) and ``exec.d2h`` (the rest of the readback); each device batch
+counts one host array in ``rlc_executor_h2d_arrays``.
 """
 from __future__ import annotations
 
@@ -87,6 +89,11 @@ class BatchExecutor:
         self._m_bat = {b: bat.labels(backend=b, shard=shard)
                        for b in BACKENDS}
         self._m_qry = {b: qry.labels(backend=b, shard=shard)
+                       for b in BACKENDS}
+        h2d = reg.counter("rlc_executor_h2d_arrays",
+                          desc="host arrays handed to the device",
+                          labelnames=("backend", "shard"))
+        self._m_h2d = {b: h2d.labels(backend=b, shard=shard)
                        for b in BACKENDS}
         self._m_fallback = reg.counter(
             "rlc_executor_fallbacks",
@@ -198,33 +205,34 @@ class BatchExecutor:
         return ws, "python"
 
     @staticmethod
-    def _pad_pow2(s, t, mr_id, n: int):
-        """Pad a real-length batch to the next power of two by repeating
-        slot 0 — batches arrive unpadded from the scheduler, and the jit
-        backends need a bounded shape set ({1, 2, 4, ...}) to avoid
-        re-tracing per fill level. Slot 0 is always a valid query; the
-        caller slices answers back to ``n``."""
+    def _pack_pow2(s, t, mr_id, n: int) -> np.ndarray:
+        """The real slots of a batch as one ``(3, cap)`` int32 host array
+        (rows ``s``, ``t``, ``mr``), padded to the next power of two by
+        repeating slot 0 — batches arrive unpadded from the scheduler, and
+        the jit backends need a bounded shape set ({1, 2, 4, ...}) to
+        avoid re-tracing per fill level. Slot 0 is always a valid query;
+        the caller slices answers back to ``n``. A fresh array per batch:
+        the device may still read it after the call returns."""
         cap = 1
         while cap < n:
             cap <<= 1
-        if cap == len(s):
-            return s, t, mr_id
-        pad = lambda a: np.concatenate(  # noqa: E731
-            [np.asarray(a[:n]), np.full(cap - n, a[0], dtype=a.dtype)])
-        return pad(s), pad(t), pad(mr_id)
+        q = np.empty((3, cap), np.int32)
+        for row, a in zip(q, (s, t, mr_id)):
+            row[:n] = a[:n]
+            row[n:] = a[0]
+        return q
 
     def _run(self, backend: str, s, t, mr_id, n: int,
              trace=None) -> np.ndarray:
         # The device backends get pow2-padded shapes (static jit set);
         # the per-query loop backends run exactly the real slots.
         if backend in ("pallas", "sorted"):
-            di = self.device_index
             with self._ph_h2d(trace):
-                args = di.inputs(*self._pad_pow2(s, t, mr_id, n))
+                q = self._pack_pow2(s, t, mr_id, n)
             with self._ph_dispatch(trace):
-                out = (di.join(*args, use_pallas=True)
-                       if backend == "pallas"
-                       else di.join(*args, method="sorted"))
+                # the jitted call transfers q itself: one host array
+                out = self.device_index.join(q, backend)
+                self._m_h2d[backend].inc()
                 # queue the readback behind the join now, as a bare
                 # np.asarray would: waiting first and only then asking
                 # for the answers costs a second round trip to the device

@@ -322,7 +322,7 @@ def test_executor_fallback_on_backend_failure(small_setup):
     class Boom:
         row_len = 8
 
-        def inputs(self, *a, **kw):
+        def join(self, *a, **kw):
             raise RuntimeError("device lost")
 
     ex = BatchExecutor(svc.index, svc.frozen, device_index=Boom(),

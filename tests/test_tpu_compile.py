@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.device_index import _query_batch_sorted_rows
+from repro.core.device_index import _join_packed, _query_batch_sorted_rows
 from repro.kernels import ops
 from repro.kernels.bitpack import pack_bits
 
@@ -72,6 +72,19 @@ def test_mergejoin_row_window_compiles_for_v5e(one_chip):
     text = _compiled_text(ops.mergejoin_query, *rows, *qs, interpret=False,
                           row_base_out=V // 2, row_base_in=V // 2)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("method,row_lo,kernel", [
+    ("pallas", 0, True), ("pallas", V // 2, True), ("sorted", V // 2, False)])
+def test_packed_join_compiles_for_v5e(one_chip, monkeypatch, method, row_lo,
+                                     kernel):
+    # the executor's entry: six layout arrays and one packed (3, Q) batch;
+    # JAX runs on the CPU here, so the kernel is told it is not
+    monkeypatch.setattr(ops, "on_cpu", lambda: False)
+    rows = [_sds(one_chip, (V // 4 + 3, E)) for _ in range(6)]
+    text = _compiled_text(_join_packed, *rows, _sds(one_chip, (3, Q)),
+                          row_lo=row_lo, num_mrs=NUM_MRS, method=method)
+    assert ("tpu_custom_call" in text) == kernel
 
 
 def test_frontier_step_many_compiles_for_v5e(one_chip):

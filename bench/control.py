@@ -3,15 +3,26 @@
 
     python bench/control.py --workload ep-batch --seeds 11,12,13 --seconds 3
 
-For each seed: the cell as the benchmark runs it, but served from a
-device layout whose rows keep only their first 8 entries, one sublane
-group of the (8, 128) tiling: the cut a shorter, better-aligned row
-length would tempt a kernel to make. The configuration guarantees exact
-answers; answers that need an entry past the cut go wrong, so the check
-has to read ``correct: false``. Rows are ordered by hub rank, so a cut
-at half the longest row costs only about 1 answer in 4,000 and would
-not test the check at all. One JSON line per seed: the check's numbers,
-and ``row_len`` of the true layout against the cut one.
+For each seed: the cell as the benchmark runs it, with the fault the
+check has to catch put under the timed path: the stale control where the
+cell's traffic writes, the rows control where it does not. One JSON line
+per seed: the check's numbers, and what the control changed.
+
+Rows (query cells): served from a device layout whose rows keep only
+their first 8 entries, one sublane group of the (8, 128) tiling: the cut
+a shorter, better-aligned row length would tempt a kernel to make. The
+configuration guarantees exact answers; answers that need an entry past
+the cut go wrong, so the check has to read ``correct: false``. Rows are
+ordered by hub rank, so a cut at half the longest row costs only about
+1 answer in 4,000 and would not test the check at all. A write rebuilds
+the layout, so this control does not reach a cell that writes.
+
+Stale (cells whose traffic writes): every write moves the graph and the
+index, but the executor keeps serving the device layout and the cached
+answers from before it: the shortcut of a write path that skips the
+relayout or the eviction. The guarantee is read-your-writes, so the
+answers that a write changes go wrong after it, and the check has to
+read ``correct: false``.
 """
 from __future__ import annotations
 
@@ -41,6 +52,35 @@ def truncate_rows(run) -> None:
     run.row_len = CUT
 
 
+def stale_writes(run) -> None:
+    """Keep the executor on its pre-write layout and cache across every
+    write of ``run``; the graph and the index still move."""
+    svc = run.svc
+    inner = svc.apply_delta
+
+    def apply_delta(delta):
+        ex, cache = svc.executor, svc.cache
+        kept = ex.index, ex.frozen, ex.device_index
+        cache.clear = lambda: None
+        cache.invalidate_rows = lambda **kw: 0
+        try:
+            return inner(delta)
+        finally:
+            del cache.clear, cache.invalidate_rows
+            ex.index, ex.frozen, ex.device_index = kept
+    svc.apply_delta = apply_delta
+
+
+def control(traffic: dict):
+    """The fault a cell's control puts in, and how it is described: the
+    stale control where the traffic writes, the rows control where it
+    does not."""
+    if "writes_per_s" in traffic:
+        return stale_writes, ("writes served from the layout and cache "
+                              "of before")
+    return truncate_rows, f"rows cut to {CUT} entries"
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -52,17 +92,18 @@ def main(argv=None) -> int:
     from bench.lib import cell as cl
     bench = cl.load_json(ROOT / "BENCHMARK.json")
     cell = cl.Cell.from_benchmark(bench, args.workload, False)
+    fault, what = control(cell.traffic)
     for seed in (int(x) for x in args.seeds.split(",")):
         full = {}
 
         def hook(run):
             full["row_len"] = run.row_len
-            truncate_rows(run)
-            full["cut"] = run.row_len
+            fault(run)
+            full["served_row_len"] = run.row_len
         out = cl.execute(cell, seed, args.seconds, False,
                          time.perf_counter(), hook=hook)
         print(json.dumps(dict(workload=args.workload, seed=seed,
-                              control=f"rows cut to {CUT} entries",
+                              control=what,
                               correct=out["correct"], **full,
                               check=out["check"])), flush=True)
     return 0

@@ -10,6 +10,8 @@ Everything a cell is made of is found by name:
   queries, loop, call size, the service fields the mix's clients set);
 * ``bench/loops/<loop>.py``: the driver the mix names (``prepare`` and
   ``window``);
+* ``bench/graphs/<generator>.py``: a graph generator the configuration
+  names, other than the Barabasi-Albert copy in ``lib/graph.py``;
 * ``bench/metrics/<metric>.py``: one reader per metric (``read(run)``).
 """
 from __future__ import annotations
@@ -26,7 +28,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from . import traffic as tf
-from .graph import make_edges
+from .graph import apply_write, make_edges
 from .reference import Reference
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -101,6 +103,8 @@ class Run:
     seconds: float
     svc: object = None
     pool: Optional[tf.Pool] = None
+    #: the edges drawn for the run, before any write
+    edges: np.ndarray = field(default_factory=lambda: np.zeros((0, 3), int))
     device_kind: str = ""
     row_len: int = 0
     setup_s: float = 0.0
@@ -115,6 +119,22 @@ class Run:
     answers: np.ndarray = field(default_factory=lambda: np.zeros(0, bool))
     #: (start, end) on the host clock of each call the window made
     calls: List[tuple] = field(default_factory=list)
+    #: writes the traffic asks for in the window; the ``(inserts,
+    #: deletes)`` rows of each applied, in order, and its seconds from
+    #: the call to the return
+    writes_due: int = 0
+    writes: List[tuple] = field(default_factory=list)
+    write_s: List[float] = field(default_factory=list)
+    #: per answer in ``served``: how many writes had returned before its
+    #: call began (all 0 when empty)
+    epochs: np.ndarray = field(default_factory=lambda: np.zeros(0, int))
+    #: pool indices of the queries the writes change, and of the probes
+    #: of the rows they touch: every answer to either is compared
+    flips: np.ndarray = field(default_factory=lambda: np.zeros(0, int))
+    probes: np.ndarray = field(default_factory=lambda: np.zeros(0, int))
+    #: seconds of set-up spent in the plain reference for the window's
+    #: traffic (drawing writes): kept out of ``setup_s``
+    reference_s: float = 0.0
     trace: object = None             # lib.trace.TraceSummary
     counters0: Dict[tuple, float] = field(default_factory=dict)
     spans: bool = False               # the loop adds bench:* spans
@@ -163,6 +183,14 @@ def warm_shapes(svc, batch_size: int) -> None:
         z = np.zeros(n, np.int32)
         svc.executor.execute(z, z, z)
         n *= 2
+
+
+def layout_ready(svc) -> None:
+    """Wait until the service's device layout is on the device."""
+    import jax
+    d = svc.device_index
+    jax.block_until_ready([d.out_hub, d.out_mr, d.in_hub, d.in_mr,
+                           d.out_key, d.in_key])
 
 
 def add_spans(svc) -> None:
@@ -273,11 +301,9 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
     run.index_s = time.perf_counter() - t0
     t0 = time.perf_counter()
     svc = RLCService.build(graph, scfg, index=index)
-    d = svc.device_index
-    jax.block_until_ready([d.out_hub, d.out_mr, d.in_hub, d.in_mr,
-                           d.out_key, d.in_key])
+    layout_ready(svc)
     run.layout_s = time.perf_counter() - t0
-    run.svc, run.row_len = svc, d.row_len
+    run.svc, run.row_len, run.edges = svc, svc.device_index.row_len, edges
     run.pool = tf.make_pool(cfg["vertices"], edges, scfg.k,
                             cell.traffic["pool"],
                             cell.traffic["walk_share"],
@@ -297,7 +323,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
         # collection now, in set-up, keeps their first sweep out of the
         # window, where it stalled one call by 100 ms or more
         gc.collect()
-        run.setup_s = time.perf_counter() - t_start
+        run.setup_s = time.perf_counter() - t_start - run.reference_s
         watch.on = True
         if trace:
             with trace_lib.capture(log_dir) as found:
@@ -330,43 +356,84 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
         out["breakdown"] = dict(device_ops=run.trace.top_ops(),
                                 idle_gaps=run.trace.top_gaps())
     run.svc = svc = None
-    check = check_answers(run, edges)
+    check = check_answers(run)
     check["compiles_in_window"] = dict(value=watch.compiles, limit=0)
     out["correct"] = all(c["value"] <= c["limit"] for c in check.values())
     out["check"] = check
     return out
 
 
-def check_answers(run: Run, edges: np.ndarray) -> Dict[str, dict]:
+def check_answers(run: Run) -> Dict[str, dict]:
     """Compare the window's answers with the plain reference.
 
     Draws ``CHECK_QUERIES`` distinct queries from the seed among those
     answered, and compares every answer the window gave to each of them,
-    cached answers included. Each number comes with its limit.
+    cached answers included; every answer to a query that a write
+    changes, or that probes a row it touches, too. Each answer is
+    compared with the reference over the graph as it stood when its call
+    began: ``run.edges`` with as many of ``run.writes`` applied as had
+    returned. Each number comes with its limit.
     """
     rng = tf.stream(run.seed, tf.SAMPLE)
-    answered = np.unique(run.served)
+    flip = np.isin(run.served, run.flips)
+    probe = np.isin(run.served, run.probes)
+    answered = np.unique(run.served[~(flip | probe)])
     picks = rng.choice(answered, size=min(CHECK_QUERIES, len(answered)),
                        replace=False) if len(answered) else answered
-    ref = Reference(run.cell.config["vertices"], edges)
-    want = dict(zip(picks.tolist(),
-                    ref.answers(run.pool.queries(picks)).tolist()))
-    sel = np.isin(run.served, picks)
-    expect = np.array([want[i] for i in run.served[sel].tolist()], bool)
-    wrong = int((run.answers[sel] != expect).sum())
+    epochs = (run.epochs if len(run.epochs)
+              else np.zeros(len(run.served), int))
+    sel = np.isin(run.served, picks) | flip | probe
+    pairs = set(zip(run.served[sel].tolist(), epochs[sel].tolist()))
+    want = {}
+    graph = run.edges
+    for e in range(max((e for _, e in pairs), default=0) + 1):
+        if e:
+            graph = apply_write(graph, *run.writes[e - 1])
+        idx = sorted(i for i, x in pairs if x == e)
+        ref = Reference(run.cell.config["vertices"], graph)
+        want.update(zip(((i, e) for i in idx),
+                        ref.answers(run.pool.queries(idx)).tolist()))
+    expect = np.array([want[p] for p in zip(run.served[sel].tolist(),
+                                            epochs[sel].tolist())], bool)
+    bad = run.answers[sel] != expect
+    wrong = int(bad.sum())
+    # each pick as the reference answered it when first compared
+    first = {}
+    for i, e in zip(run.served[sel].tolist(), epochs[sel].tolist()):
+        first.setdefault(i, e)
     walk = picks < run.pool.n_walk
-    ref_true = np.array([want[i] for i in picks.tolist()], bool)
+    ref_true = np.array([want[i, first[i]] for i in picks.tolist()], bool)
     print(f"compared {int(sel.sum())} answers to {len(picks)} distinct "
           f"queries with the reference; reference says true for "
           f"{ref_true[walk].sum()} of {walk.sum()} from walks, "
           f"{ref_true[~walk].sum()} of {(~walk).sum()} drawn false",
           file=sys.stderr, flush=True)
-    return dict(
+    check = dict(
         wrong=dict(value=wrong, limit=0),
         unanswered=dict(value=run.attempted - run.answered, limit=0),
         failed=dict(value=run.failed, limit=0),
         compared_none=dict(value=int(sel.sum() == 0), limit=0),
     )
+    if not run.writes_due:
+        return check
+    on, near = flip[sel], probe[sel]
+    per_epoch = np.bincount(epochs[sel], minlength=run.writes_due + 1)
+    print(f"writes: {len(run.writes)} of {run.writes_due} applied; "
+          f"answers compared by epoch {per_epoch.tolist()}; "
+          f"{int(on.sum())} answers to {len(np.unique(run.served[flip]))} "
+          f"distinct queries that a write changes, {int(bad[on].sum())} "
+          f"wrong; {int(near.sum())} answers to "
+          f"{len(np.unique(run.served[probe]))} probes of the rows it "
+          f"touches, {int(bad[near].sum())} wrong",
+          file=sys.stderr, flush=True)
+    check.update(
+        writes_missing=dict(value=run.writes_due - len(run.writes),
+                            limit=0),
+        after_write_none=dict(value=int(per_epoch[len(run.writes)] == 0),
+                              limit=0),
+        flips_none=dict(value=int(not on.any()), limit=0),
+    )
+    return check
 
 
 def report_check(out: dict) -> None:

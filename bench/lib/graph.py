@@ -5,10 +5,20 @@ paper's experiments use (section VI, after gMark), kept with the
 benchmark so that the data a cell serves cannot change under a later PR.
 It draws exactly what ``repro.graphgen.barabasi_albert`` draws for the
 same seed, so the two give the same edges (a test holds them equal).
+
+Any other generator a configuration names is the file
+``bench/graphs/<generator>.py``, whose ``make_edges(config, seed)``
+returns the ``(E, 3)`` int32 rows ``(src, label, dst)`` drawn from
+``seed``: a new graph family arrives as a new file.
 """
 from __future__ import annotations
 
+from pathlib import Path
+
 import numpy as np
+
+#: where generators other than ``barabasi_albert`` are found, by name
+GRAPHS = Path(__file__).resolve().parents[1] / "graphs"
 
 
 def zipf_labels(num_edges: int, num_labels: int, rng: np.random.Generator,
@@ -59,6 +69,38 @@ def barabasi_albert(num_vertices: int, m_attach: int, num_labels: int,
     return np.unique(edges.astype(np.int32), axis=0)
 
 
+def base_edges(config: dict) -> np.ndarray:
+    """The configuration's graph as drawn from its ``graph_seed``, before
+    a run renames it."""
+    gen = config["generator"]
+    if gen == "barabasi_albert":
+        return barabasi_albert(config["vertices"], config["ba_m"],
+                               config["labels"], config["graph_seed"],
+                               config["label_zipf_exponent"],
+                               config["ba_mirror_p"])
+    path = GRAPHS / f"{gen}.py"
+    if not path.is_file():
+        raise ValueError(f"unknown generator {gen!r}: no file {path}")
+    from .cell import load_module
+    edges = load_module(path).make_edges(config, config["graph_seed"])
+    return np.unique(np.asarray(edges, np.int32).reshape(-1, 3), axis=0)
+
+
+def renaming(num_vertices: int, seed: int) -> np.ndarray:
+    """The permutation by which the run ``seed`` renames vertices."""
+    return np.random.default_rng(np.random.SeedSequence([seed, 0])
+                                 ).permutation(num_vertices)
+
+
+def rename(rows: np.ndarray, perm: np.ndarray) -> np.ndarray:
+    """``(src, label, dst)`` rows with both endpoints renamed by
+    ``perm``, sorted and deduplicated."""
+    out = np.array(rows, np.int32).reshape(-1, 3)
+    out[:, 0] = perm[out[:, 0]]
+    out[:, 2] = perm[out[:, 2]]
+    return np.unique(out, axis=0)
+
+
 def make_edges(config: dict, seed: int) -> np.ndarray:
     """The edges a configuration file describes, for the run ``seed``.
 
@@ -68,18 +110,26 @@ def make_edges(config: dict, seed: int) -> np.ndarray:
     seed drawing its own graph moved the host build's time by up to 20%
     between seeds, against 2% between two runs of one seed.)
     """
-    if config["generator"] != "barabasi_albert":
-        raise ValueError(f"unknown generator {config['generator']!r}")
-    edges = barabasi_albert(config["vertices"], config["ba_m"],
-                            config["labels"], config["graph_seed"],
-                            config["label_zipf_exponent"],
-                            config["ba_mirror_p"])
-    perm = np.random.default_rng(np.random.SeedSequence([seed, 0])
-                                 ).permutation(config["vertices"])
-    out = edges.copy()
-    out[:, 0] = perm[edges[:, 0]]
-    out[:, 2] = perm[edges[:, 2]]
-    return np.unique(out, axis=0)
+    return rename(base_edges(config), renaming(config["vertices"], seed))
+
+
+def apply_write(edges: np.ndarray, inserts: np.ndarray,
+                deletes: np.ndarray) -> np.ndarray:
+    """``(edges \\ deletes) | inserts``, sorted and deduplicated: the edge
+    list after one write."""
+    kept = edges
+    if len(deletes):
+        gone = np.isin(_keys(edges), _keys(np.asarray(deletes)))
+        kept = edges[~gone]
+    rows = np.concatenate([kept, np.asarray(inserts, np.int32)
+                           .reshape(-1, 3)])
+    return np.unique(rows.astype(np.int32), axis=0)
+
+
+def _keys(rows: np.ndarray) -> np.ndarray:
+    """One int64 key per ``(src, label, dst)`` row."""
+    r = rows.astype(np.int64)
+    return (r[:, 0] << 40) | (r[:, 1] << 32) | r[:, 2]
 
 
 def out_csr(num_vertices: int, edges: np.ndarray):

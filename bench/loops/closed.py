@@ -58,14 +58,21 @@ def window(run, state):
             break
     run.window_s = ends[-1] - t0
     run.calls = list(zip([t0] + ends[:-1], ends))
-    run.calls = list(zip([t0] + ends[:-1], ends))
-    calls_ms = np.diff([t0] + ends) * 1e3
-    print(f"closed loop: {n} calls, call ms p50 {np.median(calls_ms):.2f} "
-          f"max {calls_ms.max():.2f} (call {int(calls_ms.argmax())})",
-          file=sys.stderr, flush=True)
+    record(run, "closed loop", served, values)
+
+
+def record(run, loop: str, served, values) -> np.ndarray:
+    """Keep the window's answers in ``run``: per call, the pool indices
+    sent and the values that came back. Returns the mask of answered
+    requests among those sent."""
+    calls_ms = np.array([b - a for a, b in run.calls]) * 1e3
+    print(f"{loop}: {len(calls_ms)} calls, call ms p50 "
+          f"{np.median(calls_ms):.2f} max {calls_ms.max():.2f} "
+          f"(call {int(calls_ms.argmax())})", file=sys.stderr, flush=True)
     flat = [v for vs in values for v in vs]
     good = np.array([v is not None for v in flat], bool)
     run.attempted = run.answered = len(flat)
     run.failed = int(len(flat) - good.sum())
     run.served = np.concatenate(served)[good]
     run.answers = np.array([v for v in flat if v is not None], bool)
+    return good

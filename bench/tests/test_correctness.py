@@ -18,15 +18,34 @@ def _no_persistent_cache(monkeypatch):
     monkeypatch.setattr(repro.device, "enable_compile_cache", lambda: "")
 
 
+#: the mixes a tiny run takes: the cells' own, and a test mix that
+#: writes (no cell runs one yet)
+MIXES = {"batch-shuffled": cl.BENCH / "traffic" / "batch-shuffled.json",
+         "write-stream": cl.BENCH / "tests" / "fixtures" / "write-stream.json"}
+
+#: the metrics of a mix that writes, as a cell of one would list them
+WRITE_METRICS = dict(
+    end_to_end=[dict(name="fresh_ms", unit="ms"),
+                dict(name="qps_between_writes", unit="queries/s")],
+    per_layer=[dict(name="delta_fallback_pct", unit="%"),
+               dict(name="delta_build_ms", unit="ms"),
+               dict(name="delta_rest_ms", unit="ms")])
+
+
 def tiny(traffic, trace=False):
-    """The EP-shaped configuration at 600 vertices under ``traffic``; a
-    traced run reports ``ep-batch``'s per-layer metrics."""
+    """The EP-shaped configuration at 600 vertices under ``traffic``,
+    reporting ``ep-batch``'s metrics; under writes, those of a write
+    cell in place of ``qps``."""
     bench = cl.load_json(cl.ROOT / "BENCHMARK.json")
     config = cl.load_json(cl.BENCH / "configs" / "ba-ep-4k.json")
-    mix = cl.load_json(cl.BENCH / "traffic" / f"{traffic}.json")
+    mix = cl.load_json(MIXES[traffic])
     mix.update(pool=8192, warm_calls=1)
-    return cl.Cell("tiny", 1, dict(config, vertices=600), mix,
-                   cl.select_metrics(bench, "ep-batch", trace))
+    metrics = cl.select_metrics(bench, "ep-batch", trace)
+    if "writes_per_s" in mix:
+        keep = {"index_s", "setup_s", "layout_s"}
+        metrics = [m for m in metrics if m["name"] in keep] + WRITE_METRICS[
+            "per_layer" if trace else "end_to_end"]
+    return cl.Cell("tiny", 1, dict(config, vertices=600), mix, metrics)
 
 
 def run(traffic, hook=None, trace=False, tmp_path=None):
@@ -75,26 +94,43 @@ def unwarmed_shape(run):
     run.svc.executor.execute = execute
 
 
-MIXES = ["batch-shuffled"]
-
-
 @pytest.mark.parametrize("traffic", MIXES)
 def test_sound_run_is_correct(traffic):
     out = run(traffic)
     assert out["correct"], out["check"]
     assert out["check"]["wrong"] == {"value": 0, "limit": 0}
     assert out["attempted"] > 0 and out["failed"] == 0
+    writes = traffic == "write-stream"
+    assert ("fresh_ms" in out["metrics"]) == writes
+    assert ("qps" in out["metrics"]) != writes
+    if writes:
+        assert out["check"]["writes_missing"] == {"value": 0, "limit": 0}
+        assert out["metrics"]["qps_between_writes"]["value"] > 0
 
 
-@pytest.mark.parametrize("traffic", MIXES)
-@pytest.mark.parametrize("fault", [control.truncate_rows, flip_one,
-                                   half_left_out],
-                         ids=["control_rows_cut", "answer_flipped",
-                              "half_batch_left_out"])
+#: each mix's control (a write rebuilds the layout, so only the stale
+#: control reaches one under writes) and the faults a query cell can have
+FAULTS = [(control.truncate_rows, "control_rows_cut", "batch-shuffled"),
+          (control.stale_writes, "control_stale", "write-stream")] + [
+    (fault, name, traffic) for fault, name in (
+        (flip_one, "answer_flipped"), (half_left_out, "half_batch_left_out"))
+    for traffic in MIXES]
+
+
+@pytest.mark.parametrize("traffic, fault", [
+    pytest.param(traffic, fault, id=f"{name}-{traffic}")
+    for fault, name, traffic in FAULTS])
 def test_control_and_faults_are_not_correct(traffic, fault):
     out = run(traffic, hook=fault)
     assert not out["correct"]
     assert out["check"]["wrong"]["value"] > 0
+
+
+@pytest.mark.parametrize("traffic, fault", [
+    ("batch-shuffled", control.truncate_rows),
+    ("write-stream", control.stale_writes)])
+def test_the_control_follows_the_traffic(traffic, fault):
+    assert control.control(cl.load_json(MIXES[traffic]))[0] is fault
 
 
 def test_a_compile_inside_the_window_is_not_correct():

@@ -26,6 +26,14 @@ stack the inputs into one ``(3, cap)`` int32 host array),
 and the request for its answers), ``exec.wait`` (until the answers are
 ready) and ``exec.d2h`` (the rest of the readback); each device batch
 counts one host array in ``rlc_executor_h2d_arrays``.
+
+A batch runs in two steps: :meth:`BatchExecutor.dispatch` starts it
+(``exec.h2d``, ``exec.dispatch``) and returns a :class:`PendingBatch`
+without waiting for the device; :meth:`PendingBatch.result` waits for
+the answers (``exec.wait``, ``exec.d2h``). :meth:`BatchExecutor.execute`
+is the two back to back. ``rlc_executor_batch_seconds`` records the
+executor's own time on a batch, the two steps together, and not the
+time between them.
 """
 from __future__ import annotations
 
@@ -76,7 +84,8 @@ class BatchExecutor:
         reg = self.obs.registry
         lat = reg.histogram(
             "rlc_executor_batch_seconds",
-            desc="wall time of one executed batch, by answering backend",
+            desc="executor time on one batch (dispatch plus result), by "
+                 "answering backend",
             unit="s", labelnames=("backend", "shard"))
         bat = reg.counter("rlc_executor_batches",
                           desc="batches answered, by backend",
@@ -130,19 +139,25 @@ class BatchExecutor:
         return b
 
     # ------------------------------------------------------------------ #
-    def execute(self, s: np.ndarray, t: np.ndarray, mr_id: np.ndarray,
-                n_real: Optional[int] = None,
-                backend: Optional[str] = None,
-                trace=None) -> Tuple[np.ndarray, str]:
-        """Answer a padded batch; returns ``(answers[:n_real], backend)``.
+    def dispatch(self, s: np.ndarray, t: np.ndarray, mr_id: np.ndarray,
+                 n_real: Optional[int] = None,
+                 backend: Optional[str] = None,
+                 trace=None) -> "PendingBatch":
+        """Start answering a padded batch; returns a :class:`PendingBatch`
+        whose :meth:`~PendingBatch.result` gives ``(answers[:n_real],
+        backend)``.
 
-        Runs the requested backend, or the first available one in
-        ``BACKENDS`` order when it is unavailable (counted as a
-        fallback). A backend that raises is not retried elsewhere: the
-        error propagates as :class:`ExecutorError`. ``trace``: optional
-        :class:`repro.obs.Trace`; the batch gets an ``exec:<backend>``
-        span (``args.error`` on a failed batch), the device phases nested
-        inside it.
+        A device backend packs the batch, makes the jitted join call and
+        queues the readback, then returns without waiting for the device
+        (``exec.h2d``, ``exec.dispatch``); the host backends answer at
+        once and return a handle that is already complete. Runs the
+        requested backend, or the first available one in ``BACKENDS``
+        order when it is unavailable (a fallback, counted once the batch
+        is answered). A backend that raises, here or in ``result()``, is
+        not retried elsewhere: the error propagates as
+        :class:`ExecutorError`. ``trace``: optional
+        :class:`repro.obs.Trace`; the dispatch gets an ``exec:<backend>``
+        span (``args.error`` on a failed one), the phases nested inside.
         """
         first = self.resolve(backend)
         b = next((c for c in (first,) + BACKENDS if self.available(c)),
@@ -156,20 +171,40 @@ class BatchExecutor:
         t0 = time.perf_counter()
         try:
             with span:
-                ans = self._run(b, s, t, mr_id, n, trace)
+                out = self._launch(b, s, t, mr_id, n, trace)
         except Exception as e:
-            raise ExecutorError(
-                f"backend {b!r} failed on a batch of {n} queries") from e
-        dt = time.perf_counter() - t0
-        self.recorders[b].record(dt, n)
-        self._m_lat[b].observe(dt)
+            raise _failed(b, n) from e
+        return PendingBatch(self, first, b, n, out, trace,
+                            time.perf_counter() - t0)
+
+    def execute(self, s: np.ndarray, t: np.ndarray, mr_id: np.ndarray,
+                n_real: Optional[int] = None,
+                backend: Optional[str] = None,
+                trace=None, pending: Optional["PendingBatch"] = None
+                ) -> Tuple[np.ndarray, str]:
+        """Answer a padded batch; returns ``(answers[:n_real], backend)``:
+        :meth:`dispatch`, then wait for the result. ``pending``: the
+        handle :meth:`dispatch` already returned for this batch, so that
+        only the wait is left. Every answer a facade serves comes out of
+        here, however its batch was started, so a wrapper around this
+        method (a profiler span, an injected fault) sees each batch."""
+        if pending is None:
+            pending = self.dispatch(s, t, mr_id, n_real, backend, trace)
+        ans, b = pending.result()
+        n = len(s) if n_real is None else int(n_real)
+        return ans[:n], b
+
+    def _answered(self, first: str, b: str, n: int, seconds: float) -> None:
+        """Account one answered batch: ``seconds`` is the executor's own
+        time on it, dispatch plus result."""
+        self.recorders[b].record(seconds, n)
+        self._m_lat[b].observe(seconds)
         self._m_bat[b].inc()
         self._m_qry[b].inc(n)
         if b != first:
             self.fallbacks += 1
             self._m_fallback.labels(
                 **{"from": first, "to": b, "shard": self._shard}).inc()
-        return np.asarray(ans[:n], dtype=bool), b
 
     def explain_batch(self, s: np.ndarray, t: np.ndarray,
                       mr_id: np.ndarray, n_real: Optional[int] = None,
@@ -222,8 +257,9 @@ class BatchExecutor:
             row[n:] = a[0]
         return q
 
-    def _run(self, backend: str, s, t, mr_id, n: int,
-             trace=None) -> np.ndarray:
+    def _launch(self, backend: str, s, t, mr_id, n: int, trace=None):
+        """The device backends: the join's answers, still on the device,
+        with their readback queued. The host backends: the answers."""
         # The device backends get pow2-padded shapes (static jit set);
         # the per-query loop backends run exactly the real slots.
         if backend in ("pallas", "sorted"):
@@ -237,10 +273,7 @@ class BatchExecutor:
                 # np.asarray would: waiting first and only then asking
                 # for the answers costs a second round trip to the device
                 out.copy_to_host_async()
-            with self._ph_wait(trace):
-                out.block_until_ready()
-            with self._ph_d2h(trace):
-                return np.asarray(out)
+            return out
         if backend == "numpy":
             return self.frozen.query_batch(s[:n], t[:n], mr_id[:n])
         if backend == "python":
@@ -256,3 +289,57 @@ class BatchExecutor:
         """Summaries for every backend that actually served a batch."""
         return {b: r.summary() for b, r in self.recorders.items()
                 if r.batches}
+
+
+def _failed(backend: str, n: int) -> ExecutorError:
+    return ExecutorError(f"backend {backend!r} failed on a batch of {n} "
+                         "queries")
+
+
+class PendingBatch:
+    """A batch :meth:`BatchExecutor.dispatch` has started. On a device
+    backend its answers may still be on the device; on a host backend
+    they are already here."""
+
+    __slots__ = ("_ex", "_first", "backend", "n", "_out", "_trace",
+                 "_seconds", "_answers")
+
+    def __init__(self, ex: BatchExecutor, first: str, backend: str, n: int,
+                 out, trace, seconds: float):
+        self._ex = ex
+        self._first = first
+        self.backend = backend
+        self.n = n
+        self._out = out
+        self._trace = trace
+        self._seconds = seconds          # the executor's time so far
+        self._answers: Optional[np.ndarray] = None
+
+    def done(self) -> bool:
+        """True once the device has computed the answers: :meth:`result`
+        then waits at most for their readback."""
+        return (self._answers is not None
+                or isinstance(self._out, np.ndarray)
+                or self._out.is_ready())
+
+    def result(self) -> Tuple[np.ndarray, str]:
+        """``(answers[:n], backend)``, waiting for the device if need be
+        (``exec.wait``, ``exec.d2h``). The first call accounts the batch
+        as answered, with the time spent in dispatch and here; a failure
+        raises :class:`ExecutorError` and accounts nothing."""
+        if self._answers is None:
+            ex, out, tr = self._ex, self._out, self._trace
+            t0 = time.perf_counter()
+            if not isinstance(out, np.ndarray):
+                try:
+                    with ex._ph_wait(tr):
+                        out.block_until_ready()
+                    with ex._ph_d2h(tr):
+                        out = np.asarray(out)
+                except Exception as e:
+                    raise _failed(self.backend, self.n) from e
+            self._out = None                 # drop the device buffer
+            self._answers = np.asarray(out[:self.n], dtype=bool)
+            ex._answered(self._first, self.backend, self.n,
+                         self._seconds + time.perf_counter() - t0)
+        return self._answers, self.backend

@@ -14,16 +14,26 @@ the executor (device backend with python fallback); answers backfill the
 cache. ``query_batch`` is synchronous: it drains the scheduler before
 returning, so every admitted query is answered in admission order.
 
+Within a call, a flushed batch is dispatched to the executor and the
+loop goes back to admitting while the device answers it. At each flush
+the oldest batches whose answers are back are answered; after the drain
+the call waits for the rest. A facade whose ``_run_batch`` is overridden
+(the sharded fan-out) runs each batch to the end when it flushes.
+
 Phase spans (:meth:`repro.obs.Observability.phase`, always on): the
-whole call (``query_batch``), each run of admissions between executed
-batches (``admit``), each batch end to end (``execute``) and, inside it,
-the controller update and answer fan-out after the join (``answer``).
+whole call (``query_batch``), each run of admissions between flushes
+(``admit``), each batch's dispatch (``execute``) and, later, its
+readback with the controller update and answer fan-out (``resolve``,
+around ``answer``). Where each batch runs to the end at once,
+``execute`` holds all of it, ``answer`` included.
 """
 from __future__ import annotations
 
 import time
+from collections import deque
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Callable, Deque, Dict, List, Optional, Sequence, Tuple,
+                    Union)
 
 import numpy as np
 
@@ -36,7 +46,7 @@ from repro.obs import Observability
 from .answer import SHED, Answer
 from .cache import ResultCache
 from .control import ControlPlane
-from .executor import BatchExecutor
+from .executor import BatchExecutor, PendingBatch
 from .expr import PathExpression, canonicalize, parse_expression
 from .scheduler import Batch, MicroBatcher, Request
 
@@ -107,6 +117,7 @@ def bind_phases(svc) -> None:
     svc._ph_query_batch = ph("query_batch", cat="service")
     svc._ph_admit = ph("admit", cat="admission")
     svc._ph_execute = ph("execute", cat="service")
+    svc._ph_resolve = ph("resolve", cat="service")
     svc._ph_answer = ph("answer", cat="service")
 
 
@@ -255,8 +266,14 @@ class RLCService:
             # hot path, so every span below is a single comparison away
             tr = self.obs.tracer.maybe_trace()
             admission = self.ctl.admission
-            # one admit span per run of admissions: it closes while a batch
-            # executes and reopens after
+            # batches dispatched and not yet answered, oldest first; None
+            # where _run_batch is overridden (the sharded fan-out), which
+            # runs each batch to the end when it flushes
+            inflight: Optional[Deque[Tuple[Batch, PendingBatch, float]]] = (
+                deque() if getattr(self._run_batch, "__func__", None)
+                is RLCService._run_batch else None)
+            # one admit span per run of admissions: it closes while
+            # batches are dispatched and answered, and reopens after
             admit = self._ph_admit().open()
             try:
                 for i, (s, t, constraint) in enumerate(queries):
@@ -292,13 +309,12 @@ class RLCService:
                     slot.setdefault(req.req_id, []).append(i)
                     if ready:
                         admit.close()
-                        for batch in ready:
-                            self._execute(batch, answers, slot, tr)
+                        self._serve(ready, inflight, answers, slot, tr)
                         admit.open()
             finally:
                 admit.close()
-            for batch in self.batcher.drain():
-                self._execute(batch, answers, slot, tr)
+            self._serve(self.batcher.drain(), inflight, answers, slot, tr,
+                        wait=True)
             if any(a is None for a in answers):
                 # a batch was flushed outside this call (ticker thread or
                 # a concurrent query_batch stealing a coalesced key) —
@@ -339,8 +355,39 @@ class RLCService:
         vals, _backends = self._run_batch(batch)
         return np.asarray(vals, dtype=bool)
 
-    def _execute(self, batch: Batch, answers: List[Optional[Answer]],
-                 slot: Dict[int, List[int]], tr=None) -> None:
+    def _serve(self, batches: List[Batch],
+               inflight: Optional[Deque[Tuple[Batch, PendingBatch, float]]],
+               answers: List[Optional[Answer]], slot: Dict[int, List[int]],
+               tr=None, wait: bool = False) -> None:
+        """Run flushed ``batches`` for :meth:`query_batch`. Each is
+        dispatched and queued in ``inflight``; then the batches at its
+        head are answered while their answers are back, or all of them
+        when ``wait``. With no queue (``inflight`` None) each batch runs
+        to the end here."""
+        if inflight is None:
+            for batch in batches:
+                self._execute(batch, answers, slot, tr)
+            return
+        for batch in batches:
+            self._queue_wait(batch, tr)
+            with self._ph_execute(tr, n=batch.n_real, mr_len=batch.mr_len):
+                t0 = time.perf_counter()
+                pending = self.executor.dispatch(
+                    batch.s, batch.t, batch.mr_id, batch.n_real, trace=tr)
+                inflight.append((batch, pending,
+                                 time.perf_counter() - t0))
+        while inflight and (wait or inflight[0][1].done()):
+            batch, pending, dispatch_s = inflight.popleft()
+            with self._ph_resolve(tr, n=batch.n_real, mr_len=batch.mr_len):
+                t0 = time.perf_counter()
+                vals, backend = self.executor.execute(
+                    batch.s, batch.t, batch.mr_id, batch.n_real, trace=tr,
+                    pending=pending)
+                self._answer(batch, vals, [backend] * len(batch.requests),
+                             dispatch_s + time.perf_counter() - t0,
+                             answers, slot, tr)
+
+    def _queue_wait(self, batch: Batch, tr) -> None:
         if tr is not None:
             # queue wait is measured on the scheduler's clock; only the
             # duration crosses into the tracer's timeline
@@ -349,28 +396,39 @@ class RLCService:
                               max(batch.flushed_at - oldest, 0.0),
                               cat="batcher", reason=batch.reason,
                               mr_len=batch.mr_len, n=batch.n_real)
+
+    def _execute(self, batch: Batch, answers: List[Optional[Answer]],
+                 slot: Dict[int, List[int]], tr=None) -> None:
+        """Run one batch to the end: ``_run_batch``, then the answers."""
+        self._queue_wait(batch, tr)
         with self._ph_execute(tr, n=batch.n_real, mr_len=batch.mr_len):
             t0 = time.perf_counter()
             vals, backends = self._run_batch(batch, tr)
-            exec_s = time.perf_counter() - t0
-            with self._ph_answer(tr):
-                # feed the control loops (SLO EWMAs, back-pressure queue
-                # waits); a VirtualClock scheduler clock also advances by
-                # the measured execute time so open-loop replay
-                # accumulates realistic waits
-                self.ctl.on_batch_executed(batch, exec_s)
-                advance = getattr(self.batcher.clock, "advance", None)
-                if advance is not None:
-                    advance(exec_s)
-                for req, val, backend in zip(batch.requests, vals,
-                                             backends):
-                    val = bool(val)
-                    self.cache.put((req.s, req.t, req.mr_id), val,
-                                   mr_len=batch.mr_len)
-                    ans = Answer(val, "degraded" if backend == "bibfs"
-                                 else "computed", backend)
-                    for pos in slot.get(req.req_id, ()):
-                        answers[pos] = ans
+            self._answer(batch, vals, backends, time.perf_counter() - t0,
+                         answers, slot, tr)
+
+    def _answer(self, batch: Batch, vals, backends, exec_s: float,
+                answers: List[Optional[Answer]], slot: Dict[int, List[int]],
+                tr=None) -> None:
+        """A batch's answers into the control loops, the cache and each
+        submitter's position; ``exec_s`` is the executor's time on it."""
+        with self._ph_answer(tr):
+            # feed the control loops (SLO EWMAs, back-pressure queue
+            # waits); a VirtualClock scheduler clock also advances by
+            # the measured execute time so open-loop replay
+            # accumulates realistic waits
+            self.ctl.on_batch_executed(batch, exec_s)
+            advance = getattr(self.batcher.clock, "advance", None)
+            if advance is not None:
+                advance(exec_s)
+            for req, val, backend in zip(batch.requests, vals, backends):
+                val = bool(val)
+                self.cache.put((req.s, req.t, req.mr_id), val,
+                               mr_len=batch.mr_len)
+                ans = Answer(val, "degraded" if backend == "bibfs"
+                             else "computed", backend)
+                for pos in slot.get(req.req_id, ()):
+                    answers[pos] = ans
 
     # -- EXPLAIN / provenance -------------------------------------------- #
     def explain(self, s: int, t: int, constraint: Constraint,

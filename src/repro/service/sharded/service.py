@@ -182,7 +182,10 @@ class ShardedRLCService:
     _admit = RLCService._admit
     query = RLCService.query
     query_batch = RLCService.query_batch
+    _serve = RLCService._serve
+    _queue_wait = RLCService._queue_wait
     _execute = RLCService._execute
+    _answer = RLCService._answer
     _warm_execute = RLCService._warm_execute
     _delta_backend_name = RLCService._delta_backend_name
     _ensure_delta_builder = RLCService._ensure_delta_builder

@@ -88,3 +88,79 @@ def test_h2d_arrays_one_per_device_batch(svc):
         assert bat.value(**lab) == n
         want = n if backend in ("pallas", "sorted") else 0
         assert h2d.value(**lab) == want, backend
+
+
+class _Clock:
+    """``time`` for the executor module: ``perf_counter`` reads the
+    scripted instants in order."""
+
+    def __init__(self, *instants):
+        self.instants = list(instants)
+
+    def perf_counter(self):
+        return self.instants.pop(0)
+
+
+@pytest.mark.parametrize("backend", ["pallas", "sorted", "numpy", "python"])
+def test_batch_seconds_are_dispatch_plus_result(svc, backend, monkeypatch):
+    """``rlc_executor_batch_seconds`` records a batch once, when it is
+    answered: the time in dispatch plus the time in ``result()``, not the
+    time between them."""
+    import repro.service.executor as executor_mod
+    obs = Observability()
+    ex = _executor(svc, "full", obs=obs)
+    s, t, mr = _batch(svc, "full", seed=9)
+    ref, _ = _executor(svc, "full").execute(s, t, mr, n_real=5,
+                                            backend="numpy")
+    monkeypatch.setattr(executor_mod, "time", _Clock(0.0, 1.0, 10.0, 12.0))
+    pending = ex.dispatch(s, t, mr, n_real=5, backend=backend)
+    assert pending.done() or backend in ("pallas", "sorted")
+    got, b = pending.result()
+    again, _ = pending.result()              # answered once, accounted once
+    assert b == backend and got.shape == (5,)
+    np.testing.assert_array_equal(got, ref)
+    np.testing.assert_array_equal(again, got)
+    cell = obs.registry.get("rlc_executor_batch_seconds").labels(
+        backend=backend, shard="-")
+    assert cell.reservoir.count == 1 and cell.reservoir.total == 3.0
+    assert obs.registry.get("rlc_executor_batches").value(
+        backend=backend, shard="-") == 1
+    assert ex.recorders[backend].batches == 1
+
+
+def test_failed_result_raises_and_accounts_nothing(svc):
+    """A readback that fails raises :class:`ExecutorError` from
+    ``result()``, as a failed dispatch does: the batch is not retried on
+    a host backend, and nothing counts it as answered."""
+    from repro.service import ExecutorError
+
+    class Unready:
+        def copy_to_host_async(self):
+            pass
+
+        def is_ready(self):
+            return False
+
+        def block_until_ready(self):
+            raise RuntimeError("device lost")
+
+    class LostReadback:
+        row_len = 8
+
+        def join(self, q, method):
+            return Unready()
+
+    obs = Observability()
+    ex = BatchExecutor(svc.index, svc.frozen, device_index=LostReadback(),
+                       id_to_mr=svc._id_to_mr, backend="sorted", obs=obs)
+    s, t, mr = _batch(svc, "full", seed=11)
+    pending = ex.dispatch(s, t, mr, n_real=6)
+    assert not pending.done()
+    with pytest.raises(ExecutorError, match="'sorted' failed") as err:
+        pending.result()
+    assert isinstance(err.value.__cause__, RuntimeError)
+    assert ex.fallbacks == 0 and ex.stats() == {}
+    assert obs.registry.get("rlc_executor_h2d_arrays").value(
+        backend="sorted", shard="-") == 1
+    assert obs.registry.get("rlc_executor_batches").value(
+        backend="sorted", shard="-") == 0

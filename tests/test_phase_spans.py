@@ -98,17 +98,24 @@ def test_sampled_trace_keeps_execute_over_exec_backend():
     svc.query_batch(queries)
     doc = svc.chrome_trace()
     names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
-    assert {"execute", "exec:sorted", "answer", "queue_wait"} <= names
+    assert {"execute", "resolve", "exec:sorted", "answer",
+            "queue_wait"} <= names
     assert "admit[0]" in names
     (tid,) = {e.tid for e in svc.obs.tracer.events}
-    execs = [r for r in span_tree(svc.obs.tracer.events, tid)
-             if r.event.name == "execute"]
+    roots = span_tree(svc.obs.tracer.events, tid)
+    # each batch: its dispatch under `execute`, and later its readback and
+    # answers under `resolve`
+    execs = [r for r in roots if r.event.name == "execute"]
     assert len(execs) == 6                     # 5 full batches + drain
     for root in execs:
-        kids = [c.event.name for c in root.children]
-        assert kids == ["exec:sorted", "answer"]
+        assert [c.event.name for c in root.children] == ["exec:sorted"]
         assert [c.event.name for c in root.children[0].children] == \
-            list(DEVICE_PHASES)
+            list(DEVICE_PHASES[:2])
+    resolves = [r for r in roots if r.event.name == "resolve"]
+    assert len(resolves) == 6
+    for root in resolves:
+        assert [c.event.name for c in root.children] == \
+            list(DEVICE_PHASES[2:]) + ["answer"]
 
 
 def _span_counts(svc):
@@ -126,7 +133,8 @@ def test_unsampled_path_adds_no_per_query_span():
     assert got["query_batch"] == 1
     # one admission run before each mid-loop flush, and one after the last
     assert got["admit"] == full + 1
-    for name in ("execute", "answer") + DEVICE_PHASES:
+    # each batch opens each executor span once
+    for name in ("execute", "resolve", "answer") + DEVICE_PHASES:
         assert got[name] == batches, name
     assert not svc.obs.tracer.events
 
@@ -162,20 +170,27 @@ def test_phases_land_on_the_profiler_host_line(tmp_path):
     batches = -(-len(queries) // 8)
     assert len(ev["rlc:query_batch"]) == 1
     assert len(ev["rlc:admit"]) == len(queries) // 8 + 1
-    for name in ("execute", "answer") + DEVICE_PHASES:
+    for name in ("execute", "resolve", "answer") + DEVICE_PHASES:
         assert len(ev[f"rlc:{name}"]) == batches, name
     # the annotations nest as the serving path does, on one clock
     outer = ev["test:outer"]
     assert _inside(ev["rlc:query_batch"][0], outer)
-    for name in ("rlc:admit", "rlc:execute"):
+    for name in ("rlc:admit", "rlc:execute", "rlc:resolve"):
         assert all(_inside(iv, ev["rlc:query_batch"]) for iv in ev[name])
-    for name in ("answer",) + DEVICE_PHASES:
-        assert all(_inside(iv, ev["rlc:execute"])
+    for name, parent in (("exec.h2d", "execute"), ("exec.dispatch", "execute"),
+                         ("exec.wait", "resolve"), ("exec.d2h", "resolve"),
+                         ("answer", "resolve")):
+        assert all(_inside(iv, ev[f"rlc:{parent}"])
                    for iv in ev[f"rlc:{name}"]), name
-    # admission runs and executed batches never overlap
+    # admission runs overlap no dispatch and no readback
     for a in ev["rlc:admit"]:
         assert not any(a[0] < e1 and e0 < a[1]
-                       for e0, e1 in ev["rlc:execute"])
+                       for e0, e1 in ev["rlc:execute"] + ev["rlc:resolve"])
+    # the six phases never overlap, so their shares add up to the part of
+    # the window they cover
+    six = sorted(iv for name in ("admit", "answer") + DEVICE_PHASES
+                 for iv in ev[f"rlc:{name}"])
+    assert all(a[1] <= b[0] for a, b in zip(six, six[1:]))
 
 
 def test_ticker_tick_that_flushes_is_one_span():

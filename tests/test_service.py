@@ -12,6 +12,7 @@ from repro.graphgen import erdos_renyi, fig1_graph
 from repro.service import (BatchExecutor, ExecutorError, ExpressionError,
                            MicroBatcher, RLCService, ResultCache,
                            ServiceConfig, parse_expression)
+from repro.service.executor import PendingBatch
 
 
 # ------------------------------------------------------------------ #
@@ -431,6 +432,165 @@ def test_service_stats_shape():
     assert st["executor"]["fallbacks"] >= 0
     for b in st["executor"]["backends"].values():
         assert b["p99_ms"] >= b["p50_ms"] >= 0.0
+
+
+# ------------------------------------------------------------------ #
+# Pipelined query_batch: dispatch at flush, answers read back later
+# ------------------------------------------------------------------ #
+class SyncService(RLCService):
+    """The facade with ``_run_batch`` overridden, which keeps each batch
+    on the synchronous path: run to the end when it flushes."""
+
+    def _run_batch(self, batch, tr=None):
+        return RLCService._run_batch(self, batch, tr)
+
+
+@pytest.fixture(scope="module")
+def pipe_graph():
+    g = erdos_renyi(60, 3.0, 3, seed=31)
+    return g, build_rlc_index(g, 2)
+
+
+def _span_count(svc, name):
+    series = svc.obs.registry.get("rlc_span_seconds")
+    return sum(cell.reservoir.count for key, cell in series.series()
+               if key[0] == name)
+
+
+def _pool_queries(svc, n, pool=24, seed=0):
+    """``n`` queries drawn from a pool of ``pool`` keys: repeats land in
+    one batch and in batches after it."""
+    rng = np.random.default_rng(seed)
+    keys = [(int(rng.integers(60)), int(rng.integers(60)),
+             svc._id_to_mr[int(rng.integers(len(svc._id_to_mr)))])
+            for _ in range(pool)]
+    return [keys[int(i)] for i in rng.integers(pool, size=n)]
+
+
+@pytest.mark.parametrize("call, fill", [(1, 4), (45, 4), (90, 32)],
+                         ids=["call1-fill4", "call45-fill4", "call90-fill32"])
+@pytest.mark.parametrize("backend", ["pallas", "sorted", "numpy", "python"])
+def test_pipelined_answers_as_synchronous(pipe_graph, backend, call, fill,
+                                          monkeypatch):
+    g, index = pipe_graph
+    cfg = dict(k=2, batch_size=fill, max_wait_ms=1e6, cache_capacity=0,
+               backend=backend)
+    pipe = RLCService.build(g, ServiceConfig(**cfg), index=index)
+    sync = SyncService.build(g, ServiceConfig(**cfg), index=index)
+    queries = _pool_queries(pipe, call, seed=call)
+    want = sync.query_batch(queries)
+    # nothing reads back before the drain: every batch of the call is in
+    # flight at once, repeats of one key among them
+    monkeypatch.setattr(PendingBatch, "done", lambda self: False)
+    got = pipe.query_batch(queries)
+    assert [a.value for a in got] == [a.value for a in want]
+    assert {a.backend for a in got} == {backend}
+    assert got == [bibfs_rlc(g, s, t, L) for s, t, L in queries]
+    by_key = {}
+    for q, a in zip(queries, got):
+        assert by_key.setdefault(q, a.value) == a.value
+    batches = pipe.executor.recorders[backend].batches
+    assert batches == sync.executor.recorders[backend].batches >= 1
+    assert _span_count(pipe, "resolve") == batches
+    assert _span_count(sync, "resolve") == 0
+    assert _span_count(sync, "execute") == batches
+
+
+def test_pipelined_answers_ready_batches_at_each_flush(pipe_graph,
+                                                       monkeypatch):
+    """A batch whose answers are back is answered at the next flush,
+    before the drain; one whose answers are not waits for the drain."""
+    g, index = pipe_graph
+    svc = RLCService.build(g, ServiceConfig(
+        k=2, batch_size=4, max_wait_ms=1e6, cache_capacity=0,
+        backend="sorted"), index=index)
+    at_drain = []
+    drain = svc.batcher.drain
+
+    def draining():
+        at_drain.append((_span_count(svc, "execute"),
+                         _span_count(svc, "resolve")))
+        return drain()
+    monkeypatch.setattr(svc.batcher, "drain", draining)
+    monkeypatch.setattr(PendingBatch, "done", lambda self: True)
+    svc.query_batch(_pool_queries(svc, 40, pool=40, seed=3))
+    dispatched, answered = at_drain[-1]
+    assert dispatched == answered > 0
+    dispatched = answered = _span_count(svc, "resolve")
+    monkeypatch.setattr(PendingBatch, "done", lambda self: False)
+    svc.query_batch(_pool_queries(svc, 40, pool=40, seed=4))
+    assert at_drain[-1][0] > dispatched and at_drain[-1][1] == answered
+    assert _span_count(svc, "resolve") == _span_count(svc, "execute")
+
+
+def test_lost_answers_raise_on_an_external_flush(pipe_graph, monkeypatch):
+    """A batch taken out of the scheduler outside the call leaves holes;
+    the call raises instead of answering them False."""
+    g, index = pipe_graph
+    for cls in (RLCService, SyncService):
+        svc = cls.build(g, ServiceConfig(
+            k=2, batch_size=4, max_wait_ms=1e6, cache_capacity=0,
+            backend="sorted"), index=index)
+        drain = svc.batcher.drain
+        monkeypatch.setattr(svc.batcher, "drain", lambda: drain()[1:])
+        queries = _pool_queries(svc, 10, pool=10, seed=5)
+        with pytest.raises(RuntimeError, match="lost answers"):
+            svc.query_batch(queries)
+
+
+def test_sharded_service_stays_synchronous(pipe_graph):
+    from repro.service.sharded import ShardedRLCService, ShardedServiceConfig
+    g, index = pipe_graph
+    svc = ShardedRLCService.build(g, ShardedServiceConfig(
+        k=2, batch_size=4, max_wait_ms=1e6, cache_capacity=0,
+        num_shards=2), index=index)
+    base = RLCService.build(g, ServiceConfig(k=2), index=index)
+    queries = _pool_queries(svc, 30, seed=6)
+    assert svc.query_batch(queries) == base.query_batch(queries)
+    assert _span_count(svc, "resolve") == 0
+    # each batch runs to the end inside its `execute`, `answer` included
+    assert _span_count(svc, "execute") == _span_count(svc, "answer") > 0
+
+
+def test_failed_readback_in_a_call_raises(pipe_graph):
+    """A join whose readback fails raises out of the call that dispatched
+    it, as a failed dispatch does; the service serves the next call."""
+    g, index = pipe_graph
+    svc = RLCService.build(g, ServiceConfig(
+        k=2, batch_size=4, max_wait_ms=1e6, cache_capacity=0,
+        backend="sorted"), index=index)
+    queries = _pool_queries(svc, 12, seed=7)
+    want = svc.query_batch(queries)
+    good = svc.executor.device_index
+    svc.executor.device_index = _LostReadback()
+    with pytest.raises(ExecutorError, match="'sorted' failed") as err:
+        svc.query_batch(queries)
+    assert isinstance(err.value.__cause__, RuntimeError)
+    assert svc.executor.fallbacks == 0
+    svc.executor.device_index = good
+    assert svc.query_batch(queries) == want
+
+
+class _Unready:
+    """A join result whose readback fails."""
+
+    def copy_to_host_async(self):
+        pass
+
+    def is_ready(self):
+        return False
+
+    def block_until_ready(self):
+        raise RuntimeError("device lost")
+
+
+class _LostReadback:
+    """A device layout whose joins dispatch and never read back."""
+
+    row_len = 8
+
+    def join(self, q, method):
+        return _Unready()
 
 
 # ------------------------------------------------------------------ #

@@ -29,6 +29,7 @@ Naming convention (see ``src/repro/obs/README.md`` for the taxonomy):
 """
 from __future__ import annotations
 
+import math
 import random
 from typing import Dict, Iterable, List, Optional, Tuple
 
@@ -44,14 +45,16 @@ class Reservoir:
     """Bounded sample store with exact-below-cap percentiles.
 
     Up to ``cap`` observations are stored verbatim, so percentiles are
-    exact. Past the cap, Algorithm-R uniform reservoir sampling keeps a
-    statistically representative ``cap``-sized subset (percentiles become
-    estimates); ``count`` / ``total`` / ``vmin`` / ``vmax`` are always
-    exact. The RNG is seeded deterministically so two identical runs
-    produce identical snapshots.
+    exact. Past the cap, uniform reservoir sampling (Algorithm L: one
+    draw per stored sample, not per observation) keeps a statistically
+    representative ``cap``-sized subset (percentiles become estimates);
+    ``count`` / ``total`` / ``vmin`` / ``vmax`` are always exact. The RNG
+    is seeded deterministically so two identical runs produce identical
+    snapshots.
     """
 
-    __slots__ = ("cap", "count", "total", "vmin", "vmax", "samples", "_rng")
+    __slots__ = ("cap", "count", "total", "vmin", "vmax", "samples", "_rng",
+                 "_w", "_next")
 
     def __init__(self, cap: int = 2048, seed: int = 0):
         if cap < 1:
@@ -63,6 +66,8 @@ class Reservoir:
         self.vmax = float("-inf")
         self.samples: List[float] = []
         self._rng = random.Random(seed)
+        self._w = 1.0               # Algorithm L's running weight
+        self._next = self.cap       # the count of the next stored sample
 
     def add(self, value: float) -> None:
         v = float(value)
@@ -72,12 +77,21 @@ class Reservoir:
             self.vmin = v
         if v > self.vmax:
             self.vmax = v
-        if len(self.samples) < self.cap:
+        n = self.count
+        if n <= self.cap:
             self.samples.append(v)
+            if n < self.cap:
+                return
+        elif n != self._next:
+            return
         else:
-            j = self._rng.randrange(self.count)
-            if j < self.cap:
-                self.samples[j] = v
+            self.samples[self._rng.randrange(self.cap)] = v
+        # the gap to the next stored sample: 1 - random() lies in (0, 1]
+        rnd = self._rng.random
+        self._w *= math.exp(math.log(1.0 - rnd()) / self.cap)
+        gap = (math.log(1.0 - rnd()) / math.log1p(-self._w)
+               if self._w < 1.0 else 0.0)
+        self._next = n + int(gap) + 1
 
     @property
     def exact(self) -> bool:

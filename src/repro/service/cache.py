@@ -47,11 +47,11 @@ class CacheStats:
     def hit_rate(self) -> float:
         return self.hits / self.lookups if self.lookups else 0.0
 
-    def record(self, mr_len: Optional[int], hit: bool) -> None:
+    def record(self, mr_len: Optional[int], hit: bool, n: int = 1) -> None:
         if mr_len is None:
             return
         h, m = self.by_mr_len.get(mr_len, (0, 0))
-        self.by_mr_len[mr_len] = (h + 1, m) if hit else (h, m + 1)
+        self.by_mr_len[mr_len] = (h + n, m) if hit else (h, m + n)
 
     def hit_rate_by_mr_len(self) -> Dict[int, float]:
         return {ln: h / (h + m) if h + m else 0.0
@@ -92,9 +92,8 @@ class ResultCache:
         look = reg.counter("rlc_cache_lookups",
                            desc="result-cache lookups by outcome",
                            labelnames=("outcome",))
-        self._m_hit = look.labels(outcome="hit")
-        self._m_miss = look.labels(outcome="miss")
-        self._m_expired = look.labels(outcome="expired")
+        self._m_look = {o: look.labels(outcome=o)
+                        for o in ("hit", "miss", "expired")}
         self._m_evict = reg.counter(
             "rlc_cache_evictions",
             desc="LRU capacity evictions").labels()
@@ -120,38 +119,59 @@ class ResultCache:
 
     def get(self, key: Key, mr_len: Optional[int] = None) -> Optional[bool]:
         """Answer if cached and fresh (refreshing recency), else ``None``."""
-        if self.capacity == 0:
-            self.stats.misses += 1
-            self.stats.record(mr_len, hit=False)
-            self._m_miss.inc()
+        found: Dict[Tuple[str, Optional[int]], int] = {}
+        val = self.probe(key, found, mr_len)
+        self.count({mr_len: 1}, found)
+        return val
+
+    def probe(self, key: Key, found: Dict[Tuple[str, Optional[int]], int],
+              mr_len: Optional[int] = None) -> Optional[bool]:
+        """:meth:`get` for one of a run of lookups, counted afterwards: a
+        hit or an expiry is tallied in ``found`` (``(outcome, mr_len) ->
+        n``), a miss not at all; :meth:`count` adds the run to the stats
+        and counters in one go."""
+        pair = self._d.get(key) if self.capacity else None
+        if pair is None:
             return None
-        try:
-            val, stamp = self._d[key]
-        except KeyError:
-            self.stats.misses += 1
-            self.stats.record(mr_len, hit=False)
-            self._m_miss.inc()
-            if mr_len is not None:
-                self._m_mr.labels(outcome="miss", mr_len=mr_len).inc()
-            return None
-        if self.ttl_s is not None and self.clock() - stamp >= self.ttl_s:
-            del self._d[key]
+        if self.ttl_s is not None and self.clock() - pair[1] >= self.ttl_s:
             # expired is its own outcome: the lookup found a (stale)
             # entry, so it is neither a hit nor a plain miss — it still
             # dilutes hit_rate via CacheStats.lookups
-            self.stats.expirations += 1
-            self.stats.record(mr_len, hit=False)
-            self._m_expired.inc()
-            if mr_len is not None:
-                self._m_mr.labels(outcome="expired", mr_len=mr_len).inc()
-            return None
-        self._d.move_to_end(key)
-        self.stats.hits += 1
-        self.stats.record(mr_len, hit=True)
-        self._m_hit.inc()
-        if mr_len is not None:
-            self._m_mr.labels(outcome="hit", mr_len=mr_len).inc()
+            del self._d[key]
+            outcome, val = "expired", None
+        else:
+            self._d.move_to_end(key)
+            outcome, val = "hit", pair[0]
+        k = (outcome, mr_len)
+        found[k] = found.get(k, 0) + 1
         return val
+
+    def count(self, lookups: Dict[Optional[int], int],
+              found: Dict[Tuple[str, Optional[int]], int]) -> None:
+        """Add a run of :meth:`probe` calls to the stats and the
+        ``rlc_cache_lookups`` / ``rlc_cache_mr_lookups`` counters:
+        ``lookups`` is the number of probes by MR length, ``found`` their
+        hits and expiries; the rest missed."""
+        misses = dict(lookups)
+        for (outcome, mr_len), n in found.items():
+            misses[mr_len] -= n
+            self._count(outcome, mr_len, n)
+        for mr_len, n in misses.items():
+            if n:
+                self._count("miss", mr_len, n)
+
+    def _count(self, outcome: str, mr_len: Optional[int], n: int) -> None:
+        st = self.stats
+        if outcome == "hit":
+            st.hits += n
+        elif outcome == "miss":
+            st.misses += n
+        else:
+            st.expirations += n
+        st.record(mr_len, outcome == "hit", n)
+        self._m_look[outcome].inc(n)
+        if mr_len is not None:
+            self._m_mr.labels(outcome=outcome, mr_len=mr_len).inc(n)
 
     def peek(self, key: Key) -> Optional[bool]:
         """Non-mutating probe: the cached answer if present and fresh,

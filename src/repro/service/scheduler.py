@@ -40,19 +40,21 @@ internal lock, so ticker flushes and caller admissions interleave safely.
 from __future__ import annotations
 
 import itertools
+import math
 import threading
 import time
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Tuple
+from operator import itemgetter
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
 from repro.obs import NULL_OBS
 
 
-@dataclass(frozen=True)
-class Request:
-    """One admitted query, already canonicalized to an indexed MR."""
+class Request(NamedTuple):
+    """One admitted query, already canonicalized to an indexed MR
+    (immutable; a named tuple, since a call builds one per query)."""
 
     req_id: int
     s: int
@@ -63,7 +65,14 @@ class Request:
 
     @property
     def key(self) -> Tuple[int, int, int]:
-        return (self.s, self.t, self.mr_id)
+        return self[1:4]
+
+
+#: a request from its fields in order, without the keyword handling of
+#: ``Request(...)`` (submit builds one per query)
+_new_request = Request._make
+#: a request's ``s``, ``t`` and ``mr_id`` fields
+_S_T_MR = tuple(map(itemgetter, (1, 2, 3)))
 
 
 @dataclass
@@ -106,6 +115,9 @@ class MicroBatcher:
         self.clock = clock
         self._buckets: Dict[int, List[Request]] = {}
         self._inflight: Dict[Tuple[int, int, int], Request] = {}
+        #: the earliest ``enqueued_at`` among the buckets' first requests
+        #: (inf when nothing is queued): a deadline is due only past it
+        self._oldest = math.inf
         self._ids = itertools.count()
         self._lock = threading.RLock()
         self._ticker: Optional[threading.Thread] = None
@@ -158,6 +170,7 @@ class MicroBatcher:
                now: Optional[float] = None) -> Tuple[Request, List[Batch]]:
         """Admit one request; return it plus any batches now ready (the
         request's own bucket on fill, any bucket past its deadline).
+        ``s``, ``t``, ``mr_id`` and ``mr_len`` are ints, already checked.
 
         A duplicate of an in-flight ``(s, t, mr_id)`` is coalesced: the
         already-queued request comes back (compare ``req_id``) and no new
@@ -165,25 +178,32 @@ class MicroBatcher:
         position that mapped onto that request.
         """
         with self._lock:
-            now = self.clock() if now is None else now
-            key = (int(s), int(t), int(mr_id))
-            existing = self._inflight.get(key)
-            if existing is not None:
+            if now is None:
+                now = self.clock()
+            key = (s, t, mr_id)
+            req = self._inflight.get(key)
+            if req is not None:
                 self.coalesced += 1
                 self._m_coalesced.inc()
-                # still a natural poll point for every bucket's deadline
-                return existing, self.poll(now)
-            req = Request(next(self._ids), key[0], key[1], key[2],
-                          int(mr_len), now)
-            bucket = self._buckets.setdefault(mr_len, [])
-            bucket.append(req)
-            self._inflight[key] = req
-            out: List[Batch] = []
-            cap, _wait = self.params(mr_len)
-            if len(bucket) >= cap:
-                out.append(self._flush_bucket(mr_len, "full"))
-            # An admission is also a natural poll point for other buckets.
-            out.extend(self.poll(now))
+                out: List[Batch] = []
+            else:
+                req = _new_request((next(self._ids), s, t, mr_id, mr_len,
+                                    now))
+                bucket = self._buckets.get(mr_len)
+                if bucket is None:
+                    bucket = self._buckets[mr_len] = []
+                if not bucket and now < self._oldest:
+                    self._oldest = now
+                bucket.append(req)
+                self._inflight[key] = req
+                out = ([self._flush_bucket(mr_len, "full")]
+                       if len(bucket) >= self.params(mr_len)[0] else [])
+            # An admission is also a natural poll point for every bucket.
+            # With one deadline for all, none is due while the oldest
+            # head is not, so the full poll runs only when one is.
+            if (self.params_fn is not None
+                    or now - self._oldest >= self.max_wait_s):
+                out.extend(self.poll(now))
             return req, out
 
     def poll(self, now: Optional[float] = None) -> List[Batch]:
@@ -224,6 +244,7 @@ class MicroBatcher:
                     del bucket[i]
                     self._inflight.pop(r.key, None)
                     self._m_evicted.inc()
+                    self._reset_oldest()
                     return True
             return False
 
@@ -329,13 +350,19 @@ class MicroBatcher:
         return self._ticker is not None
 
     # ------------------------------------------------------------------ #
+    def _reset_oldest(self) -> None:
+        self._oldest = min((b[0].enqueued_at for b in self._buckets.values()
+                            if b), default=math.inf)
+
     def _flush_bucket(self, mr_len: int, reason: str) -> Batch:
         bucket = self._buckets[mr_len]
         cap, _wait = self.params(mr_len)
         reqs, rest = bucket[:cap], bucket[cap:]
         self._buckets[mr_len] = rest
+        self._reset_oldest()
+        pop = self._inflight.pop
         for r in reqs:
-            self._inflight.pop(r.key, None)
+            pop(r[1:4], None)       # its key, as Request.key gives it
         if reason == "full":
             self.batches_full += 1
         elif reason == "deadline":
@@ -350,7 +377,6 @@ class MicroBatcher:
             wait_cell.observe(now - r.enqueued_at)
         # real slots only — the executor pads jit backends internally
         n = len(reqs)
-        s = np.fromiter((r.s for r in reqs), np.int32, n)
-        t = np.fromiter((r.t for r in reqs), np.int32, n)
-        mr = np.fromiter((r.mr_id for r in reqs), np.int32, n)
+        s, t, mr = (np.fromiter(map(get, reqs), np.int32, n)
+                    for get in _S_T_MR)
         return Batch(reqs, s, t, mr, mr_len, reason, flushed_at=now)

@@ -7,12 +7,14 @@ Wires the whole serving path together::
     svc.query(3, 17, "(0 1)+")                  # single, through the cache
     svc.query_batch([(s, t, "(a b)+"), ...])    # micro-batched
 
-Admission: each query's constraint is parsed/validated/canonicalized to a
-minimum repeat (:mod:`repro.service.expr`), checked against the result
-cache, and — on miss — handed to the micro-batcher. Flushed batches run on
-the executor (device backend with python fallback); answers backfill the
-cache. ``query_batch`` is synchronous: it drains the scheduler before
-returning, so every admitted query is answered in admission order.
+Admission: a call is checked whole before any of it is queued, each
+distinct constraint parsed/validated/canonicalized to a minimum repeat
+once (:mod:`repro.service.expr`); each query is then checked against
+the result cache and — on miss — handed to the micro-batcher. Flushed
+batches run on the executor (device backend with python fallback);
+answers backfill the cache. ``query_batch`` is synchronous: it drains
+the scheduler before returning, so every admitted query is answered in
+admission order.
 
 Within a call, a flushed batch is dispatched to the executor and the
 loop goes back to admitting while the device answers it. At each flush
@@ -30,7 +32,7 @@ around ``answer``). Where each batch runs to the end at once,
 from __future__ import annotations
 
 import time
-from collections import deque
+from collections import Counter, deque
 from dataclasses import dataclass
 from typing import (Callable, Deque, Dict, List, Optional, Sequence, Tuple,
                     Union)
@@ -111,8 +113,11 @@ class ServiceConfig:
 
 
 def bind_phases(svc) -> None:
-    """Bind the facade's phase spans on ``svc`` (both facades share the
-    admission loop that enters them)."""
+    """Bind the facade's phase spans and admission counter on ``svc``
+    (both facades share the admission loop that enters them)."""
+    svc._m_parsed = svc.obs.registry.counter(
+        "rlc_admit_parsed",
+        desc="constraints parsed and canonicalized at admission").labels()
     ph = svc.obs.phase
     svc._ph_query_batch = ph("query_batch", cat="service")
     svc._ph_admit = ph("admit", cat="admission")
@@ -207,15 +212,44 @@ class RLCService:
         return canonicalize(constraint, num_labels=self.graph.num_labels,
                             k=self.config.k)
 
+    def _resolve(self, constraint: Constraint) -> Tuple[int, int]:
+        """``(mr_id, mr_len)`` of one constraint: parsed, checked and
+        canonicalized (counted in ``rlc_admit_parsed``)."""
+        self._m_parsed.inc()
+        expr = self.parse(constraint)
+        return self.mr_ids[expr.mr], len(expr.mr)
+
     def _admit(self, s: int, t: int, constraint: Constraint
                ) -> Tuple[int, int, int, int]:
+        (key,), (mr_len,) = self._admit_call(((s, t, constraint),))
+        return (*key, mr_len)
+
+    def _admit_call(self, queries: Sequence[Query]
+                    ) -> Tuple[List[Tuple[int, int, int]], List[int]]:
+        """Check and resolve a whole call before any of it is admitted:
+        ``(keys, mr_lens)``, the canonical ``(s, t, mr_id)`` and MR length
+        of each query. A hashable constraint is resolved on its first
+        appearance in the call only, an unhashable one (a list) each
+        time. The first bad query raises, and nothing is queued."""
         n = self.graph.num_vertices
-        s, t = int(s), int(t)
-        if not (0 <= s < n and 0 <= t < n):
-            raise ValueError(
-                f"vertex ids ({s}, {t}) out of range [0, {n})")
-        expr = self.parse(constraint)
-        return s, t, self.mr_ids[expr.mr], len(expr.mr)
+        resolved: Dict[Constraint, Tuple[int, int]] = {}
+        keys: List[Tuple[int, int, int]] = []
+        lens: List[int] = []
+        for s, t, constraint in queries:
+            s, t = int(s), int(t)
+            if not (0 <= s < n and 0 <= t < n):
+                raise ValueError(
+                    f"vertex ids ({s}, {t}) out of range [0, {n})")
+            try:
+                mr = resolved.get(constraint)
+            except TypeError:       # unhashable
+                mr = self._resolve(constraint)
+            else:
+                if mr is None:
+                    mr = resolved[constraint] = self._resolve(constraint)
+            keys.append((s, t, mr[0]))
+            lens.append(mr[1])
+        return keys, lens
 
     # -- serving -------------------------------------------------------- #
     def query(self, s: int, t: int, constraint: Constraint) -> Answer:
@@ -254,18 +288,17 @@ class RLCService:
                 futures = [self.submit(s, t, c) for (s, t, c) in queries]
                 self._engine.flush()
                 return [f.result(timeout=60.0) for f in futures]
-            answers: List[Optional[Answer]] = [None] * len(queries)
-            # canonical (s, t, mr_id) per position, kept only when the
-            # shadow verifier wants to sample answered queries afterwards
-            keys: Optional[List[Tuple[int, int, int]]] = (
-                [None] * len(queries) if self._shadow is not None else None)
-            # scheduler req_id -> output positions (> 1 when duplicate
-            # in-flight queries were coalesced onto one request)
-            slot: Dict[int, List[int]] = {}
             # one sampled trace per query_batch call; None on the unsampled
             # hot path, so every span below is a single comparison away
             tr = self.obs.tracer.maybe_trace()
             admission = self.ctl.admission
+            observe, probe = self.ctl.observe_admit, self.cache.probe
+            submit = self.batcher.submit
+            # the call's cache hits and expiries by (outcome, mr_len), and
+            # how many queries were probed: counted once, in `finally`
+            found: Dict[Tuple[str, Optional[int]], int] = {}
+            lens: List[int] = []
+            probed = 0
             # batches dispatched and not yet answered, oldest first; None
             # where _run_batch is overridden (the sharded fan-out), which
             # runs each batch to the end when it flushes
@@ -276,16 +309,21 @@ class RLCService:
             # batches are dispatched and answered, and reopens after
             admit = self._ph_admit().open()
             try:
-                for i, (s, t, constraint) in enumerate(queries):
+                # the whole call is checked before any of it is queued
+                keys, lens = self._admit_call(queries)
+                answers: List[Optional[Answer]] = [None] * len(keys)
+                # scheduler req_id -> output positions (> 1 when duplicate
+                # in-flight queries were coalesced onto one request)
+                slot: Dict[int, List[int]] = {}
+                for i, key in enumerate(keys):
                     t0 = tr.tracer._now() if tr is not None else 0.0
-                    s, t, mr_id, mr_len = self._admit(s, t, constraint)
-                    if keys is not None:
-                        keys[i] = (s, t, mr_id)
+                    mr_len = lens[i]
                     # the frequency sketch counts every arrival (hits
                     # included): key popularity is a property of the
                     # workload, not of the cache's current contents
-                    self.ctl.observe_admit((s, t, mr_id), mr_len)
-                    hit = self.cache.get((s, t, mr_id), mr_len=mr_len)
+                    observe(key, mr_len)
+                    hit = probe(key, found, mr_len)
+                    probed = i + 1
                     if tr is not None:
                         tr.add(f"admit[{i}]", t0, tr.tracer._now() - t0,
                                cat="admission", mr_len=mr_len,
@@ -295,7 +333,7 @@ class RLCService:
                         continue
                     if admission is not None:
                         decision, victim = admission.decide(
-                            (s, t, mr_id), mr_len, self.batcher)
+                            key, mr_len, self.batcher)
                         if decision == "shed":
                             answers[i] = SHED
                             continue
@@ -304,14 +342,18 @@ class RLCService:
                             # the victim's submitters get the explicit SHED
                             for pos in slot.pop(victim.req_id, ()):
                                 answers[pos] = SHED
-                    req, ready = self.batcher.submit(s, t, mr_id, mr_len,
-                                                     now)
-                    slot.setdefault(req.req_id, []).append(i)
+                    req, ready = submit(*key, mr_len, now)
+                    pos = slot.get(req.req_id)
+                    if pos is None:
+                        slot[req.req_id] = [i]
+                    else:
+                        pos.append(i)
                     if ready:
                         admit.close()
                         self._serve(ready, inflight, answers, slot, tr)
                         admit.open()
             finally:
+                self.cache.count(Counter(lens[:probed]), found)
                 admit.close()
             self._serve(self.batcher.drain(), inflight, answers, slot, tr,
                         wait=True)
@@ -326,7 +368,7 @@ class RLCService:
             self.queries_served += len(queries)
             out: List[Answer] = answers
             self.queries_shed += sum(1 for a in out if a.shed)
-            if keys is not None:
+            if self._shadow is not None:
                 for (s, t, mr_id), ans in zip(keys, out):
                     if not ans.shed:        # no answer to verify
                         self._shadow.offer(s, t, mr_id, ans.value)

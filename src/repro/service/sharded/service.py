@@ -179,7 +179,9 @@ class ShardedRLCService:
     # backfill loop is identical; only _run_batch (scatter/gather fan-out
     # instead of one executor) differs.
     parse = RLCService.parse
+    _resolve = RLCService._resolve
     _admit = RLCService._admit
+    _admit_call = RLCService._admit_call
     query = RLCService.query
     query_batch = RLCService.query_batch
     _serve = RLCService._serve

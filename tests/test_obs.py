@@ -115,6 +115,22 @@ def test_reservoir_bounded_above_cap():
     assert 0.2 * n < r.percentile(50) < 0.8 * n
 
 
+def test_reservoir_keeps_a_uniform_sample():
+    # every observation is stored with probability cap / n, the first
+    # ones (the filled reservoir) as the last ones (the skips drawn)
+    n, cap, runs = 200, 10, 2000
+    kept = np.zeros(n)
+    for seed in range(runs):
+        r = Reservoir(cap=cap, seed=seed)
+        for i in range(n):
+            r.add(float(i))
+        assert len(r.samples) == cap
+        kept[np.asarray(r.samples, int)] += 1
+    share = kept / runs
+    for part in np.split(share, 10):
+        assert part.mean() == pytest.approx(cap / n, abs=0.005)
+
+
 def test_reservoir_summary_keys_frozen():
     r = Reservoir(cap=8)
     assert set(r.summary()) == {"count", "sum", "min", "max", "p50", "p90",

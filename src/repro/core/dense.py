@@ -1,4 +1,4 @@
-"""TPU-native dense boolean-semiring engine (DESIGN.md §3).
+"""TPU-native dense boolean-semiring engine.
 
 The paper's kernel-BFS guided by ``L^+`` is a BFS over the product automaton
 ``V x {0..m-1}``; one step is a boolean mat-vec with the label-sliced
@@ -13,7 +13,7 @@ boolean *matrix-matrix* products — MXU work. This module provides:
   PR1 is a vectorized coverage query (one boolean matmul per hub batch);
   batch size 1 reproduces the sequential pruning schedule, larger batches
   trade a few redundant entries for data-parallel throughput (soundness +
-  completeness preserved — the PLL-style argument in DESIGN.md §3).
+  completeness preserved — the PLL-style argument).
 
 Boolean values ride in float32/bf16 (MXU dtype); OR == saturating add via
 ``dot > 0``. The inner product is swappable for the Pallas kernel in

@@ -27,7 +27,7 @@ from .rlc_index import FrozenRLCIndex, RLCIndex
 class DeviceIndex:
     """Padded dense layout: (n, E) hub-id and mr-id arrays per direction.
 
-    Two query formulations (EXPERIMENTS.md §Perf, cell rlc-query-1m):
+    Two query formulations:
       * dense — (E x E) broadcast join per query (VPU-friendly inside the
         Pallas kernel where the tile stays in VMEM);
       * sorted — rows re-encoded as ascending ``hub * C + mr`` keys; the
@@ -233,7 +233,8 @@ def _query_batch_rows(out_hub, out_mr, in_hub, in_mr, s_row, t_row,
 def _query_batch_ref(out_hub, out_mr, in_hub, in_mr, s, t, mr):
     """Reference batched Algorithm 1 (also the Pallas kernel oracle):
     gather rows out[s_q], in[t_q], then :func:`join_rows`. Full-height
-    (row_lo = 0) layout form, kept for the distributed/dryrun harnesses."""
+    (row_lo = 0) layout form, used by
+    :func:`repro.core.distributed.distributed_query_batch`."""
     return join_rows(out_hub[s], out_mr[s], in_hub[t], in_mr[t], s, t, mr)
 
 
@@ -241,7 +242,7 @@ def _query_batch_ref(out_hub, out_mr, in_hub, in_mr, s, t, mr):
 def _query_batch_sorted_rows(out_key, in_key, s_row, t_row, s, t, mr,
                              num_mrs):
     """Sorted-key intersection join: O(E log E) per query, (Q, E) HBM
-    traffic (§Perf iteration 1 on rlc-query-1m). Key = hub * C + mr;
+    traffic. Key = hub * C + mr;
     PAD rows sort to INT32_MAX and never match. Rows are gathered by
     storage index; key compares use global ids."""
     ok = out_key[s_row]                   # (Q, E) ascending
@@ -264,11 +265,3 @@ def _query_batch_sorted_rows(out_key, in_key, s_row, t_row, s, t, mr,
     p3 = jnp.minimum(p3, ik.shape[1] - 1)
     c2b = jnp.take_along_axis(ik, p3, axis=1) == ks
     return case1 | jnp.any(c2a, axis=1) | jnp.any(c2b, axis=1)
-
-
-@jax.jit
-def _query_batch_sorted(out_key, in_key, s, t, mr, num_mrs):
-    """Full-height (row_lo = 0) form of the sorted-key join, kept for the
-    distributed/dryrun harnesses."""
-    return _query_batch_sorted_rows(out_key, in_key, s, t, s, t, mr,
-                                    num_mrs)

@@ -1,4 +1,4 @@
-"""Distributed RLC index build + query serving on a device mesh (DESIGN §3/§5).
+"""Distributed RLC index build + query serving on a device mesh.
 
 Layout
 ------
@@ -8,15 +8,8 @@ Layout
 * semiring matmuls: row-parallel ``shard_map`` — each shard holds a row
   block of the left operand, all-gathers the right operand once per step
   (ring all-gather on the ICI), and emits its row block of the product.
-  This is the *manual-collective* path; a GSPMD path (`jit` +
-  ``with_sharding_constraint``) is provided for comparison and used by the
-  dry-run lowering.
 * queries: embarrassingly parallel — sharded over ``("pod", "data")``; the
   frozen index is replicated per pod (paper's serving story).
-
-Fault tolerance: the hub-batched build checkpoints ``(OUT, IN, next_hub)``
-between batches (see :mod:`repro.ft.elastic`), so a failed build resumes
-from the last completed batch, and a shrunk mesh re-shards the same arrays.
 """
 from __future__ import annotations
 

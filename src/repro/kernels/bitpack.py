@@ -1,11 +1,10 @@
 """Pallas TPU kernel: bit-packed OR-AND matmul (beyond-paper optimization).
 
-Rationale (DESIGN §3): once the reachability frontier saturates, the
-semiring matmul is *memory-bound* — its operands are 0/1 values occupying
-a full f32 lane each. Packing the N dimension 32-to-a-uint32 cuts HBM
-traffic of the right operand and the output by 32x, trading MXU dots for
-VPU ``where``+``or`` ops. Profitable exactly when the memory roofline term
-dominates (see EXPERIMENTS.md §Perf for the napkin math + measurement).
+Rationale: once the reachability frontier saturates, the semiring matmul
+is *memory-bound* — its operands are 0/1 values occupying a full f32 lane
+each. Packing the N dimension 32-to-a-uint32 cuts HBM traffic of the right
+operand and the output by 32x, trading MXU dots for VPU ``where``+``or``
+ops. Profitable exactly when the memory roofline term dominates.
 
 ``out_packed[m, w] = OR_k a[m, k] ? b_packed[k, w] : 0``   (bitwise OR)
 """

@@ -1,6 +1,6 @@
 """Pallas TPU kernel: blocked OR-AND (boolean semiring) matmul.
 
-The compute hot-spot of the TPU-native RLC engine (DESIGN §3): reachability
+The compute hot-spot of the TPU-native RLC engine: reachability
 closures and MR step-matrix chains are chains of these products. 0/1 values
 ride in f32/bf16 so the MXU does the AND-accumulate as a dot; OR is the
 ``> 0`` threshold applied once per output tile on the f32 accumulator.

@@ -10,11 +10,10 @@ slice payloads so a worker that *rejoins* after leaving (crash, drain,
 scale-up) can be re-initialized at the current generation without
 touching the serving path.
 
-Membership is elastic in the :mod:`repro.ft.elastic` sense: workers
-join/leave at any time, each change bumps a membership epoch, routing
-simply skips dead or fenced members, and the per-worker
-``StragglerMonitor`` from that module watches round-trip times so a
-slow host is visible before it is gone. Rolling ``hot_swap`` /
+Membership is elastic: workers join/leave at any time, each change
+bumps a membership epoch, routing simply skips dead or fenced members,
+and a per-worker :class:`StragglerMonitor` watches round-trip times so
+a slow host is visible before it is gone. Rolling ``hot_swap`` /
 ``apply_delta`` are **fenced per worker**: the worker is taken out of
 routing, sent the new generation, and unfenced — its replica siblings
 (or the controller's exact BiBFS degrade path) cover the gap, mirroring
@@ -30,6 +29,7 @@ import itertools
 import multiprocessing
 import threading
 import time
+from collections import deque
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -41,10 +41,33 @@ from .transport import (RemoteError, RpcEndpoint, RpcError, RpcListener,
                         WorkerGone)
 from .worker import worker_main
 
-__all__ = ["RpcShardCluster", "RpcWorkerHandle", "WorkerLost"]
+__all__ = ["RpcShardCluster", "RpcWorkerHandle", "StragglerMonitor",
+           "WorkerLost"]
 
 RPC_METHODS = ("init", "execute", "gather_digest", "join_digest", "swap",
                "stats", "ping", "shutdown")
+
+
+class StragglerMonitor:
+    """Rolling window of per-call times: a call slower than ``factor x``
+    the window's median is flagged as a straggler."""
+
+    def __init__(self, window: int = 16, factor: float = 2.0):
+        self.window = window
+        self.factor = factor
+        self.times: deque = deque(maxlen=window)
+        self.flagged: List[Tuple[int, float]] = []
+
+    def record(self, step: int, seconds: float) -> bool:
+        """Returns True if this call is a straggler outlier."""
+        is_out = False
+        if len(self.times) >= max(4, self.window // 2):
+            med = float(np.median(self.times))
+            if seconds > self.factor * med:
+                self.flagged.append((step, seconds))
+                is_out = True
+        self.times.append(seconds)
+        return is_out
 
 
 class WorkerLost(RpcError):
@@ -67,7 +90,7 @@ class RpcWorkerHandle:
         #: fenced workers are skipped by routing (mid-swap, draining)
         self.fenced = False
         self.pid = proc.pid if proc is not None else None
-        self.straggler = None       # ft.elastic.StragglerMonitor, lazy
+        self.straggler: Optional[StragglerMonitor] = None
         self.calls = 0
 
     @property
@@ -202,7 +225,6 @@ class RpcShardCluster:
                    replica_id=h.replica_id, **payload)
         h.generation = int(payload["generation"])
         # per-worker round-trip watch
-        from repro.ft.elastic import StragglerMonitor
         h.straggler = StragglerMonitor(window=32, factor=4.0)
 
     def _on_join(self, event: str) -> None:

@@ -2,8 +2,7 @@
 
 Incoming ``(s, t, mr)`` requests accumulate into fixed-size batches so the
 batched engines (XLA sorted-key / Pallas dense) amortize dispatch and keep
-a single jit specialization per batch shape — the same slot pattern as the
-LM serving engine (:mod:`repro.serve.engine`), transplanted to queries.
+a single jit specialization per batch shape.
 
 Buckets are keyed by MR length: all requests in a batch share ``|MR|``, so
 Zipf-heavy short constraints don't ride in batches padded for long ones,

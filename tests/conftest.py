@@ -7,6 +7,6 @@ _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
-# Smoke tests and benches must see exactly ONE device; only launch/dryrun.py
-# sets the 512-device flag (in its own process, before importing jax).
+# Smoke tests and benches must see exactly ONE device; a test that needs a
+# multi-device CPU mesh sets XLA_FLAGS in a process of its own.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")

@@ -17,7 +17,7 @@ from repro.core.minimum_repeat import enumerate_mrs, mr_id_space
 from repro.graphgen import erdos_renyi, random_delta
 from repro.service import RLCService, ServiceConfig
 from repro.service.rpc import ShardWorker, wire
-from repro.service.rpc.controller import _slice_payload
+from repro.service.rpc.controller import StragglerMonitor, _slice_payload
 from repro.service.sharded import ShardedRLCService, ShardedServiceConfig
 from repro.service.stats import validate_stats
 
@@ -84,6 +84,14 @@ def test_wire_roundtrips_scalars_arrays_and_nesting():
 
 def test_wire_codec_name_is_declared():
     assert wire.codec_name() in ("msgpack", "json")
+
+
+def test_straggler_monitor_flags_outliers():
+    mon = StragglerMonitor(window=8, factor=2.0)
+    flagged = [mon.record(i, 0.1) for i in range(8)]
+    assert not any(flagged)
+    assert mon.record(9, 0.5) is True
+    assert mon.record(10, 0.11) is False
 
 
 # ------------------------------------------------------------------ #

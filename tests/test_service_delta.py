@@ -8,8 +8,10 @@ import numpy as np
 import pytest
 
 from repro.build import GraphDelta, build_rlc_index
+from repro.core.baselines import bibfs_rlc
 from repro.core.minimum_repeat import enumerate_mrs
-from repro.graphgen import erdos_renyi, random_delta
+from repro.core.queries import biased_true_queries
+from repro.graphgen import barabasi_albert, erdos_renyi, random_delta
 from repro.service import RLCService, ServiceConfig
 from repro.service.cache import ResultCache
 from repro.service.sharded import ShardedRLCService, ShardedServiceConfig
@@ -46,6 +48,72 @@ def test_service_apply_delta_answers():
         assert got == want
     assert svc.stats()["deltas_applied"] == 3
     assert svc.stats()["build"]["backend"].startswith("delta[")
+
+
+def oracle_queries(g, k, seed, extra=()):
+    """A true/false mix on ``g`` (true ones from walks) and the BiBFS
+    oracle's answers to it."""
+    qs = biased_true_queries(g, k, n=12, seed=seed)
+    queries = qs.true_queries + qs.false_queries + list(extra)
+    return queries, [bibfs_rlc(g, s, t, L) for s, t, L in queries]
+
+
+DELTA_GRAPHS = {
+    "er": lambda: erdos_renyi(60, 2.2, 3, seed=44),
+    "ba": lambda: barabasi_albert(60, 2, 3, seed=45),
+}
+
+
+@pytest.mark.parametrize("backend", ["pallas", "sorted", "numpy", "python"])
+@pytest.mark.parametrize("graph", sorted(DELTA_GRAPHS))
+def test_service_delta_chain_per_backend(graph, backend):
+    """A chain of insert+delete deltas served by one backend: after each
+    one, every answer is the oracle's on the mutated graph."""
+    g = DELTA_GRAPHS[graph]()
+    svc = RLCService.build(g, ServiceConfig(
+        k=2, batch_size=16, backend=backend, delta_fallback_frac=1.0))
+    rng = np.random.default_rng(46)
+    for step in range(3):
+        svc.apply_delta(random_delta(svc.graph, 2, 2, rng))
+        queries, want = oracle_queries(svc.graph, 2, seed=step)
+        got = svc.query_batch(queries)
+        assert [a.value for a in got] == want, step
+        assert {a.backend for a in got} <= {backend, None}
+    assert svc.executor.recorders[backend].batches >= 3
+    assert svc.stats()["deltas_applied"] == 3
+
+
+@pytest.mark.parametrize("k", [2, 3])
+@pytest.mark.parametrize("backend", ["pallas", "sorted"])
+@pytest.mark.parametrize("graph", ["er", "ba"])
+def test_delta_lengthening_longest_row_grows_device_rows(graph, backend, k):
+    """A write that makes the longest index row longer than the device
+    rows: the layout is re-padded to hold it, no entry is cut, and the
+    device join still answers as the oracle does."""
+    g = (erdos_renyi(40, 1.0, 3, seed=41) if graph == "er"
+         else barabasi_albert(40, 1, 3, seed=42))
+    svc = RLCService.build(g, ServiceConfig(
+        k=k, batch_size=16, cache_capacity=0, backend=backend,
+        delta_fallback_frac=1.0))
+    queries, want = oracle_queries(g, k, seed=0)
+    assert [a.value for a in svc.query_batch(queries)] == want
+    row_len0 = svc.device_index.row_len
+    svc.apply_delta(random_delta(svc.graph, 40, 0,
+                                 np.random.default_rng(0)))
+    fz = svc.frozen
+    assert fz.max_row > row_len0
+    assert svc.device_index.row_len >= fz.max_row
+    assert svc.stats()["index"]["row_len"] == svc.device_index.row_len
+    # every query out of the longest out-row and into the longest in-row
+    s_long = int(np.argmax(np.diff(fz.out_indptr)))
+    t_long = int(np.argmax(np.diff(fz.in_indptr)))
+    mrs = enumerate_mrs(g.num_labels, k)
+    extra = [(s_long, v, mrs[v % len(mrs)]) for v in range(40)] + \
+        [(v, t_long, mrs[v % len(mrs)]) for v in range(40)]
+    queries, want = oracle_queries(svc.graph, k, seed=1, extra=extra)
+    got = svc.query_batch(queries)
+    assert [a.value for a in got] == want
+    assert {a.backend for a in got} == {backend}
 
 
 def test_service_apply_delta_invalid_delta_raises():
